@@ -125,16 +125,15 @@ def run(scale: int = 12, edge_factor: int = 12, out_path: str = "BENCH_rescale.j
 
     # ---- forced-8-device mode: the same plans as on-mesh migrations --------
     md = _spawn_multidevice(scale, edge_factor)
-    if md is not None:
-        record["multidevice"] = md
-        for row in md["sweep"]:
-            emit(
-                f"rescale/mesh8/k{row['k_old']}->{row['k_new']}",
-                row["exec_us"],
-                f"cross_dev_bytes={row['cross_device_bytes']};"
-                f"on_dev_edges={row['on_device_edges']};"
-                f"max_dev_ops={max(d['copy_ops'] for d in row['per_device'])}",
-            )
+    record["multidevice"] = md
+    for row in md["sweep"]:
+        emit(
+            f"rescale/mesh8/k{row['k_old']}->{row['k_new']}",
+            row["exec_us"],
+            f"cross_dev_bytes={row['cross_device_bytes']};"
+            f"on_dev_edges={row['on_device_edges']};"
+            f"max_dev_ops={max(d['copy_ops'] for d in row['per_device'])}",
+        )
 
     record["peak_rss_mb"] = round(peak_rss_mb(), 1)
     with open(out_path, "w") as fh:
@@ -209,6 +208,7 @@ def _spawn_multidevice(scale: int, edge_factor: int):
     count is fixed at import, so the parent can't widen its own platform)."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"  # forced host devices; the parent may hold the chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run(
@@ -217,12 +217,11 @@ def _spawn_multidevice(scale: int, edge_factor: int):
         capture_output=True, text=True, timeout=600, env=env, cwd=root,
     )
     if r.returncode != 0:
-        emit("rescale/mesh8/FAILED", 0.0, (r.stderr or r.stdout).strip()[-200:])
-        return None
+        raise RuntimeError(f"8-device rescale child failed:\n{(r.stderr or r.stdout)[-2000:]}")
     for line in r.stdout.splitlines():
         if line.startswith(_JSON_MARK):
             return json.loads(line[len(_JSON_MARK):])
-    return None
+    raise RuntimeError("8-device rescale child printed no result")
 
 
 if __name__ == "__main__":

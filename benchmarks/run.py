@@ -6,8 +6,12 @@ Prints ``name,us_per_call,derived`` CSV (see common.emit). Individual benches:
 """
 from __future__ import annotations
 
+import os
 import sys
 import time
+import traceback
+
+from repro import compat
 
 MODULES = [
     ("fig9_partition_time", "benchmarks.bench_partition_time"),
@@ -31,6 +35,7 @@ def main() -> None:
 
     wanted = [a.lower() for a in sys.argv[1:]]
     print("name,us_per_call,derived")
+    failed = []
     for tag, modname in MODULES:
         if wanted and not any(w in tag for w in wanted):
             continue
@@ -39,9 +44,14 @@ def main() -> None:
         try:
             mod.run()
             print(f"# {tag}: done in {time.time()-t0:.1f}s", flush=True)
-        except Exception as e:  # keep the suite going; a failed bench is a bug
-            print(f"# {tag}: FAILED {type(e).__name__}: {e}", flush=True)
+        except Exception:  # run the rest, then exit nonzero: a failed bench is a bug
+            traceback.print_exc()
+            print(f"# {tag}: FAILED", flush=True)
+            failed.append(tag)
+    if failed:
+        sys.exit(f"failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":
+    compat.use_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     main()

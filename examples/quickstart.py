@@ -100,11 +100,10 @@ print(f"proc {jax.process_index()}/{jax.process_count()}: 4->6 moved "
       f"process boundary ({stats.devices} devices)")
 """
     res = spawn_local_cluster(2, 2, ["-c", worker], timeout=300.0)
-    if res.ok:
-        for p in res.procs:
-            print(f"  {p.stdout.strip()}")
-    else:  # e.g. a jaxlib without CPU collectives — the single-host story above stands
-        print("  multi-host demo skipped (no localhost process-group support here)")
+    if not res.ok:
+        raise RuntimeError(f"multi-host demo failed:\n{res.format_logs()}")
+    for p in res.procs:
+        print(f"  {p.stdout.strip()}")
 
     # 8. ASYNC FULL REBUILD: when drift escalates past the partial rung, the
     #    whole-graph re-order runs as a device program against SHADOW buffers
@@ -302,25 +301,24 @@ for step in range(40):
             time.sleep(0.05)
         detect_s = time.time() - t_kill
         cluster.kill(0, reason="stranded survivor abandoned with the group")
-    except TimeoutError:  # no localhost process-group support here
-        cluster.wait(10.0)
-        print("  fault drill skipped (no localhost process-group support here)")
-    else:
-        cluster.wait(30.0)
-        o5, info = SlotCheckpoint(drill_dir + "/ckpt", interval=2).restore()
-        eng5 = StreamingEngine.from_restored(o5, MM.make_graph_mesh(1))
-        ctl5 = EC.ElasticController(4)
-        ctl5.attach_stream(eng5)
-        fev, sev = ctl5.report_failure([2, 3], detect_s=detect_s,
-                                       reason="process lease expired",
-                                       restored_bytes=info["bytes_read"])
-        eng5.verify_bit_identity()
-        print(f"fault drill: killed p1 mid-stream, lease expired after "
-              f"{detect_s:.2f}s; restored batch {info['step']} from snapshot "
-              f"chunks + {info['replayed']} WAL records ({info['bytes_read']:,}B), "
-              f"k {fev.k_old} -> {fev.k_new} over the survivors "
-              f"(events: {' -> '.join(e.kind for e in ctl5.events)}); recovered "
-              f"pack bit-identical to the host slot state")
+    except TimeoutError as e:
+        res = cluster.wait(10.0)
+        raise RuntimeError(f"fault drill: the stream never started:\n{res.format_logs()}") from e
+    cluster.wait(30.0)
+    o5, info = SlotCheckpoint(drill_dir + "/ckpt", interval=2).restore()
+    eng5 = StreamingEngine.from_restored(o5, MM.make_graph_mesh(1))
+    ctl5 = EC.ElasticController(4)
+    ctl5.attach_stream(eng5)
+    fev, sev = ctl5.report_failure([2, 3], detect_s=detect_s,
+                                   reason="process lease expired",
+                                   restored_bytes=info["bytes_read"])
+    eng5.verify_bit_identity()
+    print(f"fault drill: killed p1 mid-stream, lease expired after "
+          f"{detect_s:.2f}s; restored batch {info['step']} from snapshot "
+          f"chunks + {info['replayed']} WAL records ({info['bytes_read']:,}B), "
+          f"k {fev.k_old} -> {fev.k_new} over the survivors "
+          f"(events: {' -> '.join(e.kind for e in ctl5.events)}); recovered "
+          f"pack bit-identical to the host slot state")
 
 
 if __name__ == "__main__":

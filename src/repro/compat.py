@@ -1,25 +1,19 @@
-"""Version-portable JAX import surface (support policy: jax >= 0.4.35).
-
-`shard_map` has moved twice and renamed a kwarg along the way:
-
-* jax 0.4.35 … 0.5.x — ``jax.experimental.shard_map.shard_map`` with a
-  ``check_rep=`` argument;
-* newer jax — top-level ``jax.shard_map`` where the argument is ``check_vma=``
-  (varying-manual-axes checking, the successor of replication checking).
+"""The repo's one JAX import surface (support policy: the installed jax 0.9.0).
 
 Repo rule: **never import shard_map directly** — always go through this
-module, which resolves whichever implementation the installed jax provides
-and translates ``check_vma`` to ``check_rep`` on older versions.
+module, so that a later move or rename of a JAX entry point is absorbed in
+one place.
 
-The module also centralises two helpers the repo used to re-derive ad hoc:
-mesh axis-size lookup and a donation-safe ``jit`` wrapper (buffer donation is
-a no-op-with-warning on CPU; the wrapper keeps programs identical across
-backends without spamming warnings on host-only test runs).
+The module also centralises helpers the repo used to re-derive ad hoc: mesh
+axis-size lookup, a donation-safe ``jit`` wrapper (buffer donation is a
+no-op-with-warning on CPU; the wrapper keeps programs identical across
+backends without spamming warnings on host-only test runs), and the
+persistent compilation cache location that entry points set.
 """
 from __future__ import annotations
 
 import functools
-import inspect
+import os
 import re
 import warnings
 
@@ -39,6 +33,7 @@ __all__ = [
     "process_count",
     "array_from_process_local_data",
     "profiler_annotation",
+    "use_compile_cache",
 ]
 
 
@@ -49,35 +44,16 @@ def _version_tuple(v: str) -> tuple:
 JAX_VERSION: tuple = _version_tuple(jax.__version__)
 
 
-def _resolve_shard_map():
-    impl = getattr(jax, "shard_map", None)
-    if not callable(impl):
-        from jax.experimental.shard_map import shard_map as impl  # jax >= 0.4.35
-    return impl
-
-
-_SHARD_MAP = _resolve_shard_map()
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_SHARD_MAP).parameters)
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """Portable shard_map: new-style ``check_vma`` spelled for whatever the
-    installed jax accepts (``check_rep`` before the rename)."""
+    """``jax.shard_map``; ``check_vma=None`` keeps jax's default."""
     if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_PARAMS:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in _SHARD_MAP_PARAMS:
-            kwargs["check_rep"] = check_vma
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
 
 
 def axis_size(axis_name: str):
-    """Size of a mapped mesh axis from inside shard_map — ``lax.axis_size``
-    where the installed jax has it, ``psum(1)`` (same value, traced) before
-    it existed."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Size of a mapped mesh axis from inside shard_map."""
+    return lax.axis_size(axis_name)
 
 
 def mesh_axis_sizes(mesh) -> dict:
@@ -93,27 +69,11 @@ def mesh_axis_size(mesh, axis: str, default: int = 1) -> int:
 
 # --------------------------------------------------------------- distributed
 def enable_cpu_collectives(impl: str = "gloo") -> bool:
-    """Turn on cross-process collectives for the CPU backend.
-
-    The knob has moved across jax releases: newer jax has the enum flag
-    ``jax_cpu_collectives_implementation`` ("gloo" / "mpi"); 0.4.x spells the
-    gloo case as the bool flag ``jax_cpu_enable_gloo_collectives``; very old
-    jaxlibs have neither (multi-process CPU unsupported). Returns True when a
-    knob was found and set. Must run before the CPU backend initializes —
-    i.e. before the first jax.devices()/computation in the process.
-    """
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", impl)
-        return True
-    except (AttributeError, ValueError):
-        pass
-    if impl == "gloo":
-        try:
-            jax.config.update("jax_cpu_enable_gloo_collectives", True)
-            return True
-        except (AttributeError, ValueError):
-            pass
-    return False
+    """Turn on cross-process collectives for the CPU backend. Returns True
+    once set. Must run before the CPU backend initializes — i.e. before the
+    first jax.devices()/computation in the process."""
+    jax.config.update("jax_cpu_collectives_implementation", impl)
+    return True
 
 
 def distributed_initialize(coordinator_address: str, num_processes: int, process_id: int) -> None:
@@ -139,31 +99,31 @@ def process_count() -> int:
 
 
 def array_from_process_local_data(sharding, local_data, global_shape):
-    """``jax.make_array_from_process_local_data`` with the keyword spelling
-    that works across supported versions (``global_shape`` became optional /
-    keyword-only along the way)."""
-    try:
-        return jax.make_array_from_process_local_data(sharding, local_data, global_shape)
-    except TypeError:
-        return jax.make_array_from_process_local_data(
-            sharding, local_data, global_shape=global_shape
-        )
+    """``jax.make_array_from_process_local_data``."""
+    return jax.make_array_from_process_local_data(sharding, local_data, global_shape)
 
 
 def profiler_annotation(name: str):
-    """A ``jax.profiler`` trace annotation context for ``name`` — makes host
-    spans (obs/trace.py) visible inside a jax profiler capture so device
-    program time can be correlated with them. The annotation class has been
-    spelled both ``TraceAnnotation`` and ``TraceContext`` across releases;
-    a null context when the installed jax has neither (annotation is an
-    optional correlation aid, never load-bearing)."""
-    prof = getattr(jax, "profiler", None)
-    cls = getattr(prof, "TraceAnnotation", None) or getattr(prof, "TraceContext", None)
-    if cls is None:
-        import contextlib
+    """A ``jax.profiler.TraceAnnotation`` for ``name`` — makes host spans
+    (obs/trace.py) visible inside a jax profiler capture so device program
+    time can be correlated with them."""
+    return jax.profiler.TraceAnnotation(name)
 
-        return contextlib.nullcontext()
-    return cls(name)
+
+def use_compile_cache(checkout: str) -> str:
+    """Point JAX's persistent compilation cache at ``<checkout>/.jax_cache``,
+    unless ``JAX_COMPILATION_CACHE_DIR`` is set, in which case JAX's own
+    reading of that variable stands. Returns the directory in use.
+
+    For entry points only (``chip_smoke.py``, ``benchmarks/run.py``): the
+    path is part of every cache key, so it is fixed inside the checkout, and
+    importing the package never turns the cache on (tests stay uncached)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(os.path.abspath(checkout), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def donate_jit(fn=None, *, donate_argnums=(), **jit_kwargs):

@@ -64,7 +64,7 @@ def decode_attention_partials(
     scale: float | None = None,
     block_s: int = DEFAULT_BLOCK_S,
     softcap: float | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     bh, gq, d = q.shape
     s = k.shape[1]

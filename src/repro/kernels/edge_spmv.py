@@ -45,7 +45,7 @@ def _spmv_kernel(src_ref, dst_ref, w_ref, x_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def spmv_blocked(src_local, dst_local, weights, x_windows, interpret: bool = True):
+def spmv_blocked(src_local, dst_local, weights, x_windows, *, interpret: bool):
     """Per-chunk gather-reduce. Returns (C, W_V) partial y windows."""
     c, w_e = src_local.shape
     w_v = x_windows.shape[1]

@@ -95,7 +95,7 @@ def flash_attention(
     softcap: float | None = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_kv: int = DEFAULT_BLOCK_KV,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, h, s, d = q.shape
     assert k.shape == (b, h, s, d) and v.shape == (b, h, s, d)

@@ -3,10 +3,10 @@
 The escalation ladder's top rung (DESIGN.md §9/§11) re-orders EVERY live slot,
 not just a degraded span. This module generalizes the span-repair kernel of
 ``kernels/span_reorder.py`` from span scope to whole-graph scope, keeping the
-same program shape — an order kernel finished by one fused multi-key
-``lax.sort`` whose unique slot key makes the composite a total order (any
-correct sort, host np.lexsort included, yields the identical permutation) —
-and the same differential-oracle discipline: ``full_order_host`` is the
+same program shape — an order kernel finished by a multi-key sort
+(``lexsort_slots``) whose slot tie-break makes the composite a total order
+(any correct sort, host np.lexsort included, yields the identical
+permutation) — and the same differential-oracle discipline: ``full_order_host`` is the
 byte-exact numpy mirror of ``full_order_device``, proven by the differential
 tests, so the engine advances host bookkeeping without a device round-trip.
 
@@ -23,10 +23,10 @@ step-parallel form of GEO's greedy itself (core/ordering.py Algorithm 4):
    by neighbor; here they share a step and sort by the neighbor key), then
    eagerly order the two-hop edges e_{u,w} whose w was touched within δ —
    the same Line-11 recency test, with M updated at step granularity.
-3. Every ordered edge records (step, phase, key_a, key_b); the final 5-key
-   ``lax.sort`` (step, phase, key_a, key_b, slot) IS the order. Dead slots
-   key to int32-max and sort last, so the permutation is live-first like the
-   span kernel's.
+3. Every ordered edge records (step, phase, key_a, key_b); the final sort
+   by (step, phase, key_a, key_b, slot) IS the order. Dead slots key to
+   int32-max and sort last, so the permutation is live-first like the span
+   kernel's.
 
 The step-granular M makes this a coarser recency than the sequential greedy's
 per-edge M — measured within 1.05× of host ``geo_order``'s RF across the
@@ -59,6 +59,7 @@ from .segment_rf import PAD_ID
 from .span_reorder import (
     eval_ks,
     identity_candidate,
+    lexsort_slots,
     span_objective_device,
     span_objective_host,
 )
@@ -313,9 +314,8 @@ def full_order_device(u, v, valid, num_vertices: int, alpha, beta, delta, permpo
 
     s = lax.while_loop(cond, body, state0)
     step, phase, ka, kb = s[7], s[8], s[9], s[10]
-    slot = jnp.arange(cap, dtype=jnp.int32)
-    # One fused 5-key sort — the whole-graph twin of the span kernel's finish.
-    return lax.sort((step, phase, ka, kb, slot), num_keys=5)[4]
+    # The whole-graph twin of the span kernel's finish.
+    return lexsort_slots((step, phase, ka, kb))
 
 
 # ------------------------------------------------------- objective + selection

@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container) so the kernel bodies
-execute in Python-on-CPU for validation; on a TPU backend the same calls lower
-to Mosaic.
+``interpret_mode()`` is the one place that decides how a kernel runs: lowered
+to Mosaic on a TPU backend, interpreted (the kernel body as plain JAX ops)
+everywhere else. The kernel modules themselves take ``interpret`` with no
+default.
 """
 from __future__ import annotations
 
@@ -20,6 +21,7 @@ from .segment_rf import PAD_ID
 
 __all__ = [
     "on_tpu",
+    "interpret_mode",
     "replication_factor_kernel",
     "chunked_spmv",
     "flash_attention",
@@ -32,7 +34,7 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _interp() -> bool:
+def interpret_mode() -> bool:
     return not on_tpu()
 
 
@@ -56,7 +58,7 @@ def replication_factor_kernel(src_ordered, dst_ordered, k: int, num_vertices: in
         ids = np.concatenate([src_ordered[lo:hi], dst_ordered[lo:hi]]).astype(np.int32)
         rows[p, : ids.shape[0]] = ids
     rows = jnp.sort(jnp.asarray(rows), axis=1)
-    counts = _rf.segment_distinct_counts(rows, interpret=_interp())
+    counts = _rf.segment_distinct_counts(rows, interpret=interpret_mode())
     return float(jnp.sum(counts)) / float(num_vertices)
 
 
@@ -91,7 +93,7 @@ def chunked_spmv(src, dst, weights, x, chunk_bounds, window_starts, window_size:
     xw = np.stack([x[ws : ws + window_size] for ws in window_starts])
     y_win = _spmv.spmv_blocked(
         jnp.asarray(src_l), jnp.asarray(dst_l), jnp.asarray(wts), jnp.asarray(xw),
-        interpret=_interp(),
+        interpret=interpret_mode(),
     )
     y = np.zeros_like(x)
     y_win = np.asarray(y_win)
@@ -103,10 +105,10 @@ def chunked_spmv(src, dst, weights, x, chunk_bounds, window_starts, window_size:
 
 
 def flash_attention(q, k, v, **kw):
-    kw.setdefault("interpret", _interp())
+    kw.setdefault("interpret", interpret_mode())
     return _fa.flash_attention(q, k, v, **kw)
 
 
 def decode_attention(q, k, v, cache_len, **kw):
-    kw.setdefault("interpret", _interp())
+    kw.setdefault("interpret", interpret_mode())
     return _dec.decode_attention(q, k, v, cache_len, **kw)

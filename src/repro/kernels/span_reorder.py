@@ -24,7 +24,7 @@ instead of GEO's sequential greedy —
 3. Edges sort by (label, depth, lo endpoint, hi endpoint, slot): one
    neighborhood at a time, inner edges before fringe edges. The slot key makes
    the composite unique, so ANY correct sort yields the same permutation —
-   host np.lexsort and device jnp.lexsort agree bit-for-bit.
+   host np.lexsort and the device's ``lexsort_slots`` agree bit-for-bit.
 
 Candidate selection (``select_span_order_*``): the repair never commits blind.
 The program scores its expansion order AND a caller-supplied candidate
@@ -39,8 +39,8 @@ Objective evaluation is tombstone-aware (dead slots key to PAD and count
 nothing) and, where profitable, runs the distinct counting through the Pallas
 boundary-count kernel of ``kernels/segment_rf.py`` — the per-(chunk, k) key
 rows are exactly that kernel's sorted-row layout. The Pallas path is gated to
-single-device/single-process meshes; the jnp fallback computes the identical
-integers.
+single-device/single-process meshes; the jnp count computes the identical
+integers on every other mesh.
 
 Everything here sticks to int32-range arithmetic (jax x64 is off by default),
 mirrored in int64 by numpy without divergence.
@@ -54,6 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import cep
+from .ops import interpret_mode
 from .segment_rf import PAD_ID, segment_distinct_counts
 
 __all__ = [
@@ -64,6 +65,7 @@ __all__ = [
     "span_objective_host",
     "select_span_order_host",
     "span_order_device",
+    "lexsort_slots",
     "span_objective_device",
     "select_span_order_device",
     "splice_targets_device",
@@ -182,7 +184,6 @@ def select_span_order_host(
 def span_order_device(u, v, valid, num_vertices: int, rounds: int = SPAN_ROUNDS):
     """Traced twin of ``span_order_host``. ``u``/``v`` int32 (cap,), ``valid``
     bool (cap,); returns the (cap,) permutation, live slots first."""
-    cap = u.shape[0]
     ui = jnp.where(valid, u, 0)
     vi = jnp.where(valid, v, 0)
 
@@ -206,11 +207,23 @@ def span_order_device(u, v, valid, num_vertices: int, rounds: int = SPAN_ROUNDS)
     dep = jnp.where(valid, jnp.minimum(depth[ui], depth[vi]), 0)
     lo = jnp.where(valid, jnp.minimum(u, v), 0)
     hi = jnp.where(valid, jnp.maximum(u, v), 0)
-    slot = jnp.arange(cap, dtype=jnp.int32)
-    # One fused 5-key sort; the unique slot key makes the composite a total
-    # order, so the sorted slot column IS the permutation (and any correct
-    # sort — np.lexsort on the host — produces the identical one).
-    return jax.lax.sort((comp, dep, lo, hi, slot), num_keys=5)[4]
+    # The slot breaks every tie, so the composite is a total order and any
+    # correct sort — np.lexsort on the host — gives the identical permutation.
+    return lexsort_slots((comp, dep, lo, hi))
+
+
+def lexsort_slots(keys):
+    """The permutation that sorts slots by ``keys`` (most significant first)
+    and then by slot: ``np.lexsort((slot,) + keys[::-1])``.
+
+    Stable one-key sorts from the least significant key up, not one
+    ``lax.sort`` over every key: the TPU compiler's cost grows steeply with
+    a sort's key count (for v5e, a 5-key sort of 65,536 slots took 252 s to
+    compile, four stable one-key sorts 29 s)."""
+    perm = jnp.arange(keys[0].shape[0], dtype=jnp.int32)
+    for key in reversed(keys):
+        perm = jax.lax.sort((key[perm], perm), num_keys=1, is_stable=True)[1]
+    return perm
 
 
 def _chunk_keys_device(u, v, valid, order, n, ks):
@@ -242,12 +255,12 @@ def span_objective_device(u, v, valid, order, n, ks, *, use_pallas: bool):
     """Traced twin of ``span_objective_host`` (identical integer result).
 
     ``use_pallas=True`` routes the distinct counting through the segment_rf
-    boundary-count kernel (interpret mode — CPU/VPU friendly); the jnp path is
-    the same boundary comparison inline, for meshes where a Pallas custom call
-    cannot be SPMD-partitioned."""
+    boundary-count kernel (Mosaic on a TPU, interpreted elsewhere); the jnp
+    path is the same boundary comparison inline, for meshes where a Pallas
+    custom call cannot be SPMD-partitioned."""
     keys = jnp.sort(_chunk_keys_device(u, v, valid, order, n, ks), axis=-1)
     if use_pallas:
-        return jnp.sum(segment_distinct_counts(keys))
+        return jnp.sum(segment_distinct_counts(keys, interpret=interpret_mode()))
     prev = jnp.concatenate(
         [jnp.full((keys.shape[0], 1), -1, keys.dtype), keys[:, :-1]], axis=1
     )
@@ -268,6 +281,23 @@ def select_span_order_device(
     return jnp.where(obj_cand < obj_vec, candidate.astype(jnp.int32), vec)
 
 
+def _mul_div(a, b, c):
+    """⌊a·b / c⌋ in int32 without forming a·b, for 0 ≤ a ≤ c < 2^30 and
+    0 ≤ b < 2^31. Binary long multiplication over a's bits keeps a quotient
+    (at most b) and a remainder below c, so nothing leaves int32 — the
+    host's int64 product overflows int32 once a region holds ~46k edges."""
+    qb, rb = b // c, b % c
+    q = jnp.zeros_like(a)
+    r = jnp.zeros_like(a)
+    for bit in range(30, -1, -1):
+        q, r = 2 * q, 2 * r
+        q, r = jnp.where(r >= c, q + 1, q), jnp.where(r >= c, r - c, r)
+        on = (a >> bit) & 1
+        q, r = q + on * qb, r + on * rb
+        q, r = jnp.where(r >= c, q + 1, q), jnp.where(r >= c, r - c, r)
+    return q
+
+
 def splice_targets_device(n, span_regions: int, spr: int, cap: int):
     """Span-local slot target of each order position — the traced twin of the
     host ``_rewrite_span`` splice: CEP chunks of the n live edges over the
@@ -278,5 +308,6 @@ def splice_targets_device(n, span_regions: int, spr: int, cap: int):
     start = cep.chunk_start(n, span_regions, p).astype(jnp.int32)
     nxt = cep.chunk_start(n, span_regions, p + 1).astype(jnp.int32)
     n_p = jnp.maximum(nxt - start, 1)
-    col = ((j - start) * jnp.int32(spr)) // n_p
-    return jnp.where(j < n, p * jnp.int32(spr) + col, jnp.int32(cap))
+    live = j < n
+    col = _mul_div(jnp.where(live, j - start, 0), jnp.int32(spr), n_p)
+    return jnp.where(live, p * jnp.int32(spr) + col, jnp.int32(cap))

@@ -504,6 +504,9 @@ def launch_local_cluster(
     procs = []
     for pid in range(n_procs):
         env = dict(os.environ)
+        # A CPU cluster: on a TPU host the parent may hold the chip, and a
+        # child that inherited its platform would fail to claim it.
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = force_host_device_flags(devs_per_proc, env.get("XLA_FLAGS", ""))
         env[ENV_COORD] = coord
         env[ENV_NPROCS] = str(n_procs)

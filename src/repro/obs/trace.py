@@ -17,8 +17,8 @@ A ``Tracer`` records nested host spans — ``with tracer.span("ingest.scatter")`
   Chrome-trace/Perfetto JSON with one track per process × phase.
 * **Device-correlatable.** ``Tracer(annotate=True)`` additionally enters a
   ``jax.profiler`` TraceAnnotation for every span (via
-  ``compat.profiler_annotation`` — a null context on jax builds without it),
-  so host spans line up with device programs inside a jax profiler capture.
+  ``compat.profiler_annotation``), so host spans line up with device
+  programs inside a jax profiler capture.
 
 The phase of a span defaults to the dotted prefix of its name
 (``"ingest.scatter"`` → phase ``"ingest"``); phases become the per-process

@@ -1,8 +1,12 @@
-"""compat layer: portable shard_map / axis_size / mesh helpers / donate_jit.
+"""compat layer: shard_map / axis_size / mesh helpers / donate_jit / cache.
 
 The repo rule is "never import shard_map directly" — these tests pin the
 behaviours the rest of the codebase relies on, on whatever jax is installed.
 """
+import os
+import pathlib
+import subprocess
+import sys
 import warnings
 
 import jax
@@ -17,7 +21,6 @@ from repro.launch import mesh as MM
 
 
 def test_no_direct_shard_map_imports_outside_compat():
-    import pathlib
     import re
 
     # Catches every spelling: "from jax import lax, shard_map" (the seed
@@ -38,7 +41,7 @@ def test_no_direct_shard_map_imports_outside_compat():
 
 
 def test_jax_version_tuple():
-    assert compat.JAX_VERSION >= (0, 4, 35), "support policy: jax >= 0.4.35"
+    assert compat.JAX_VERSION[:2] == (0, 9), "support policy: the installed jax 0.9.0"
 
 
 def test_shard_map_runs_with_check_vma_kwarg():
@@ -134,3 +137,37 @@ def test_put_global_and_local_shard_rows_degenerate_single_process():
     assert [(lo, hi) for lo, hi, _ in blocks] == [(0, 6)]
     np.testing.assert_array_equal(blocks[0][2], arr)
     np.testing.assert_array_equal(MH.host_read(committed), arr)
+
+
+# ------------------------------------------------------------ compile cache
+_CACHE_PROBE = """
+import sys
+import jax, jax.numpy as jnp
+from repro import compat
+print(compat.use_compile_cache(sys.argv[1]))
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.jit(lambda x: x * 2 + 1)(jnp.arange(4)).block_until_ready()
+"""
+
+
+@pytest.mark.parametrize("env_set", [False, True])
+def test_compile_cache_lands_in_checkout_or_env_dir(tmp_path, env_set):
+    """Without JAX_COMPILATION_CACHE_DIR the entry points cache in
+    <checkout>/.jax_cache; with it, in that directory and nowhere else."""
+    checkout = tmp_path / "checkout"
+    checkout.mkdir()
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    src = str(pathlib.Path(compat.__file__).parents[1])
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=src)
+    want = checkout / ".jax_cache"
+    if env_set:
+        want = tmp_path / "env_cache"
+        env["JAX_COMPILATION_CACHE_DIR"] = str(want)
+    r = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE, str(checkout)],
+        capture_output=True, text=True, env=env, cwd=tmp_path, timeout=120,
+    )
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.strip().splitlines()[-1] == str(want)
+    written = sorted(p.parent for p in tmp_path.rglob("*-cache"))
+    assert written and set(written) == {want}

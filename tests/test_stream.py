@@ -423,6 +423,35 @@ def test_span_repair_never_worse_deterministic(seed, span, delta):
     _check_span_repair_never_worse_than_geo(seed, span, delta)
 
 
+@pytest.mark.parametrize(
+    "n,span,spr",
+    [
+        (5, 2, 8),
+        (62_366, 1, 115_968),  # (j - start)·spr passes 2^31 from ~46k edges
+        (1_060_001, 1, 1_979_648),
+        (3_000_001, 3, 1_979_648),
+    ],
+)
+def test_splice_targets_device_match_host_layout_past_int32_products(n, span, spr):
+    """The device splice must land every live edge on the slot the host
+    ``_rewrite_span`` computes in int64, at region sizes whose int32
+    product would overflow."""
+    import jax
+
+    from repro.core import cep
+    from repro.kernels import span_reorder as SRK
+
+    cap = span * spr
+    got = np.asarray(jax.jit(SRK.splice_targets_device, static_argnums=(1, 2, 3))(
+        np.int32(n), span, spr, cap))
+    j = np.arange(n, dtype=np.int64)
+    p = np.asarray(cep.id2p(n, span, j), dtype=np.int64)
+    bounds = np.asarray(cep.chunk_bounds(n, span), dtype=np.int64)
+    want = p * spr + ((j - bounds[p]) * spr) // (bounds[p + 1] - bounds[p])
+    np.testing.assert_array_equal(got[:n], want)
+    assert np.all(got[n:] == cap)
+
+
 def _force_partial_engine(mode, seed=7, span_regions=2):
     g, o = _degraded_orderer(seed, span_regions=span_regions, scale=6)
     # Thresholds pinned so the monitor fires the partial rung every batch and
