@@ -516,8 +516,14 @@ class ElasticController:
 
     def _execute(self, decision: ScaleDecision) -> ScaleEvent:
         """Dispatch a ScaleDecision against whatever engine is attached and
-        sequence the resulting ScaleEvent. Pure plan (no engine): the CEP
-        model supplies the migration fraction."""
+        sequence the resulting ScaleEvent, as the span ``rescale.event``
+        (counts ``k_old``, ``k_new``). Pure plan (no engine): the CEP model
+        supplies the migration fraction."""
+        with self.tracer.span("rescale.event") as sp:
+            sp.count(k_old=decision.k_old, k_new=decision.k_new)
+            return self._execute_inner(decision)
+
+    def _execute_inner(self, decision: ScaleDecision) -> ScaleEvent:
         kind, k_old, k_new, lost, reason = (
             decision.kind,
             decision.k_old,

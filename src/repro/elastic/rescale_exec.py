@@ -340,8 +340,6 @@ class ElasticRescaler:
             jax.block_until_ready(new_edges)
         elapsed = time.perf_counter() - t0
         m = self.metrics
-        m.histogram("rescale.migrate_s").observe(elapsed)
-        m.counter("rescale.migrated_bytes").inc(stats_base.migrated_bytes)
         m.counter("rescale.cross_device_bytes").inc(stats_base.cross_device_bytes)
         m.counter("rescale.cross_process_bytes").inc(stats_base.cross_process_bytes)
 

@@ -503,7 +503,9 @@ def comm_volume_per_iteration(data: EngineData, bytes_per_value: int = 8) -> int
 # only retrace when (k_pad, e_cap) actually changes. SSSP's source is a
 # traced int32 operand, so querying a new source is a cache hit, not a
 # retrace. Programs iterate over the ``graph`` mesh axis (the sharded-pack
-# layout both ShardedEngineData and StreamingEngine.data use).
+# layout both ShardedEngineData and StreamingEngine.data use). Their jitted
+# functions are named ``query_<kind>``, so a profiler trace reads them as
+# ``jit_query_<kind>``.
 
 
 def _pagerank_program(v: int, mesh, axis: str, iterations: int, damping: float):
@@ -517,7 +519,7 @@ def _pagerank_program(v: int, mesh, axis: str, iterations: int, damping: float):
 
     step = _sharded(local, mesh, axis, extra_in=(P(),), extra_out=P())
 
-    def run(edges, mask, degrees):
+    def query_pagerank(edges, mask, degrees):
         deg = jnp.maximum(degrees, 1.0)
         dangling = degrees == 0
 
@@ -530,7 +532,7 @@ def _pagerank_program(v: int, mesh, axis: str, iterations: int, damping: float):
         x, _ = lax.scan(body, x0, None, length=iterations)
         return x
 
-    jitted = jax.jit(run)
+    jitted = jax.jit(query_pagerank)
 
     def call(edges, mask, degrees):
         with mesh:
@@ -566,11 +568,11 @@ def _sssp_program(v: int, mesh, axis: str, max_iters: int):
 
         return body
 
-    def run(edges, mask, source):
+    def query_sssp(edges, mask, source):
         d0 = jnp.full((v,), inf).at[source].set(0.0)
         return lax.while_loop(cond, body_fn(edges, mask), (d0, jnp.bool_(True), 0))
 
-    jitted = jax.jit(run)
+    jitted = jax.jit(query_sssp)
 
     def call(edges, mask, source=0):
         with mesh:
@@ -606,11 +608,11 @@ def _wcc_program(v: int, mesh, axis: str, max_iters: int):
 
         return body
 
-    def run(edges, mask):
+    def query_wcc(edges, mask):
         l0 = jnp.arange(v, dtype=jnp.float32)
         return lax.while_loop(cond, body_fn(edges, mask), (l0, jnp.bool_(True), 0))
 
-    jitted = jax.jit(run)
+    jitted = jax.jit(query_wcc)
 
     def call(edges, mask):
         with mesh:

@@ -3,7 +3,9 @@
 The three pieces (DESIGN.md §13):
 
 * ``trace``        — near-zero-overhead nested span recording into bounded
-                     per-process rings (disabled = a single branch).
+                     per-process rings (disabled = a single branch), with
+                     parent ids, per-span counts and compiles credited to
+                     the span they happened in.
 * ``trace_export`` — Chrome-trace/Perfetto JSON with one track per
                      process × phase; per-process fragments merge into one
                      aligned timeline.
@@ -13,7 +15,7 @@ The three pieces (DESIGN.md §13):
                      in one ``psum_host`` collective.
 * ``log``          — the controller event stream as diffable JSONL.
 """
-from .trace import SpanRecord, Tracer, get_tracer, set_tracer, span  # noqa: F401
+from .trace import SpanRecord, Tracer, get_tracer, self_times, set_tracer, span  # noqa: F401
 from .metrics import (  # noqa: F401
     NULL,
     Counter,

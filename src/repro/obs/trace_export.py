@@ -11,6 +11,8 @@ a complete ("X") event and metadata ("M") events name the tracks:
   default: ``ingest`` / ``rung`` / ``rebuild`` / ``rescale`` / ``transfer``),
   named via ``thread_name`` metadata events, so a merged multi-process trace
   renders as process → phase swimlanes.
+* **args** = the span's ``counts`` (what ``span.count`` attached, and the
+  compiles credited to it), where it has any.
 * **ts / dur** in microseconds, on the ABSOLUTE wall timeline reconstructed
   from the tracer's paired (perf_counter, wall) epoch — which is what makes
   fragments from different processes line up when ``merge_traces`` puts them
@@ -69,17 +71,18 @@ def chrome_trace(tracer: Tracer, *, process: int = 0, process_name: str | None =
         )
     base_us = (tracer.wall0 - tracer.pc0) * 1e6
     for s in spans:
-        events.append(
-            {
-                "name": s.name,
-                "cat": s.phase,
-                "ph": "X",
-                "pid": process,
-                "tid": tid_of[s.phase],
-                "ts": base_us + s.t0 * 1e6,
-                "dur": (s.t1 - s.t0) * 1e6,
-            }
-        )
+        ev = {
+            "name": s.name,
+            "cat": s.phase,
+            "ph": "X",
+            "pid": process,
+            "tid": tid_of[s.phase],
+            "ts": base_us + s.t0 * 1e6,
+            "dur": (s.t1 - s.t0) * 1e6,
+        }
+        if s.counts:
+            ev["args"] = dict(s.counts)
+        events.append(ev)
     return {
         "traceEvents": events,
         "displayTimeUnit": "ms",

@@ -40,9 +40,12 @@ import numpy as np
 
 from ..core import cep, metrics, ordering
 from ..core.graph import Graph
+from ..obs import trace as OT
 from .updates import EdgeUpdateBatch
 
 __all__ = ["StreamConfig", "IncrementalOrderer", "SlotOp", "best_insert_position"]
+
+_NO_SLOTS: frozenset = frozenset()  # the incident set of a vertex with no edges
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,7 +124,17 @@ class IncrementalOrderer:
     The slot array is the host source of truth the device streaming pack
     mirrors slot-for-slot (``ingest.StreamingEngine``); ``drain_ops`` hands
     the engine exactly the slots each ``apply`` touched.
+
+    ``tracer`` is the ``obs.trace.Tracer`` its spans go to: the streaming
+    engine hands over its own at construction; None means the process-global
+    one.
     """
+
+    tracer = None
+    # Work counts of the batch being applied (``apply`` resets and reports
+    # them): incident-set entries the median unions, free-list entries read
+    # or copied, grows, and placements that took the δ-window fallback.
+    _incident_entries = _free_entries = _grows = _fallbacks = 0
 
     def __init__(
         self,
@@ -153,6 +166,10 @@ class IncrementalOrderer:
 
     # ------------------------------------------------------------ properties
     @property
+    def _spans(self):
+        return self.tracer if self.tracer is not None else OT.get_tracer()
+
+    @property
     def regions(self) -> int:
         return self._regions
 
@@ -175,10 +192,13 @@ class IncrementalOrderer:
         return max(1, self.capacity // self.config.k_max)
 
     # ---------------------------------------------------------------- layout
-    def _layout(self, src_o: np.ndarray, dst_o: np.ndarray, regions: int, spr: Optional[int] = None) -> None:
+    def _layout(self, src_o: np.ndarray, dst_o: np.ndarray, regions: int,
+                spr: Optional[int] = None, span: str = "layout") -> None:
         """(Re)build the slot array from an ordered list: CEP chunk at
         k=regions, each chunk's edges spread evenly over its region's slots so
-        gaps are interleaved (PMA style) and early inserts never shift."""
+        gaps are interleaved (PMA style) and early inserts never shift. Its
+        steps are spans ``<span>.fill``, ``.edge_map``, ``.region_counts``
+        and ``.incident``."""
         e = int(src_o.shape[0])
         if spr is None:
             raw = max(2, int(np.ceil(e * (1.0 + self.config.slack) / regions)))
@@ -224,30 +244,35 @@ class IncrementalOrderer:
         # Vectorized fill (the same CEP spread the device splice computes):
         # the per-edge dict/set bookkeeping below is bulk-built — this runs on
         # every full rebuild and relayout, so it must not out-cost geo_order.
-        bounds = np.asarray(cep.chunk_bounds(e, regions), dtype=np.int64)
-        sizes = np.diff(bounds)
-        if int(sizes.max()) > self._spr:
-            p_bad = int(np.argmax(sizes))
-            raise ValueError(
-                f"region {p_bad} chunk ({int(sizes[p_bad])} edges) exceeds "
-                f"slots_per_region={self._spr}"
-            )
-        j = np.arange(e, dtype=np.int64)
-        p = np.asarray(cep.id2p(e, regions, j), dtype=np.int64)
-        n_p = bounds[p + 1] - bounds[p]
-        cols = ((j - bounds[p]) * self._spr) // n_p
-        slots = p * self._spr + cols
-        self.slot_src[slots] = src_o
-        self.slot_dst[slots] = dst_o
-        self.slot_valid[slots] = True
-        self._free -= np.bincount(p, minlength=regions)
-        self._edge2slot = dict(zip(zip(src_o.tolist(), dst_o.tolist()), slots.tolist()))
-        self._rebuild_region_counts(0, regions, p, src_o, dst_o)
-        idx, ws, starts, ends = self._vertex_groups(np.concatenate([src_o, dst_o]))
-        sslots = np.concatenate([slots, slots])[idx].tolist()
-        self._incident = {
-            w: set(sslots[a:b]) for w, a, b in zip(ws, starts, ends)
-        }
+        tr = self._spans
+        with tr.span(span + ".fill"):
+            bounds = np.asarray(cep.chunk_bounds(e, regions), dtype=np.int64)
+            sizes = np.diff(bounds)
+            if int(sizes.max()) > self._spr:
+                p_bad = int(np.argmax(sizes))
+                raise ValueError(
+                    f"region {p_bad} chunk ({int(sizes[p_bad])} edges) exceeds "
+                    f"slots_per_region={self._spr}"
+                )
+            j = np.arange(e, dtype=np.int64)
+            p = np.asarray(cep.id2p(e, regions, j), dtype=np.int64)
+            n_p = bounds[p + 1] - bounds[p]
+            cols = ((j - bounds[p]) * self._spr) // n_p
+            slots = p * self._spr + cols
+            self.slot_src[slots] = src_o
+            self.slot_dst[slots] = dst_o
+            self.slot_valid[slots] = True
+            self._free -= np.bincount(p, minlength=regions)
+        with tr.span(span + ".edge_map"):
+            self._edge2slot = dict(zip(zip(src_o.tolist(), dst_o.tolist()), slots.tolist()))
+        with tr.span(span + ".region_counts"):
+            self._rebuild_region_counts(0, regions, p, src_o, dst_o)
+        with tr.span(span + ".incident"):
+            idx, ws, starts, ends = self._vertex_groups(np.concatenate([src_o, dst_o]))
+            sslots = np.concatenate([slots, slots])[idx].tolist()
+            self._incident = {
+                w: set(sslots[a:b]) for w, a, b in zip(ws, starts, ends)
+            }
 
     def _set_baseline(self) -> None:
         """Record the current normalized objective as 'fresh-GEO quality'.
@@ -315,9 +340,14 @@ class IncrementalOrderer:
     # ----------------------------------------------------------------- apply
     def apply(self, batch: EdgeUpdateBatch) -> dict:
         """Apply one update batch to the slot array. Returns counts
-        {inserted, deleted, skipped}. Deletes run first so a batch that
-        replaces edges reuses the freed slots. Device-mirror ops accumulate in
-        ``drain_ops`` order-insensitively (last write per slot wins)."""
+        {inserted, deleted, skipped} and the batch's work: incident_entries
+        (sizes of the incident sets the placement medians unioned),
+        free_entries (free-list entries read or copied), grows, and
+        append_fallbacks (placements that took the δ-window fallback).
+        Deletes run first so a batch that replaces edges reuses the freed
+        slots. Device-mirror ops accumulate in ``drain_ops``
+        order-insensitively (last write per slot wins). The two loops are the
+        spans ``ingest.apply.delete`` and ``ingest.apply.insert``."""
         ins = batch.insert
         if ins.size:
             # Whole-batch range check, vectorized (negative ids would silently
@@ -332,18 +362,25 @@ class IncrementalOrderer:
             # below; the queued copy replays onto the rebuilt order at commit.
             self._rebuild_delta.append(batch)
         inserted = deleted = skipped = 0
-        for u, v in batch.delete.tolist():
-            if self._delete(int(u), int(v)):
-                deleted += 1
-            else:
-                skipped += 1
-        for u, v in batch.insert.tolist():
-            r = self._insert(int(u), int(v))
-            if r is None:
-                skipped += 1
-            else:
-                inserted += 1
-        return {"inserted": inserted, "deleted": deleted, "skipped": skipped}
+        self._incident_entries = self._free_entries = self._grows = self._fallbacks = 0
+        tr = self._spans
+        with tr.span("ingest.apply.delete"):
+            for u, v in batch.delete.tolist():
+                if self._delete(int(u), int(v)):
+                    deleted += 1
+                else:
+                    skipped += 1
+        with tr.span("ingest.apply.insert"):
+            for u, v in batch.insert.tolist():
+                r = self._insert(int(u), int(v))
+                if r is None:
+                    skipped += 1
+                else:
+                    inserted += 1
+        return {"inserted": inserted, "deleted": deleted, "skipped": skipped,
+                "incident_entries": self._incident_entries,
+                "free_entries": self._free_entries, "grows": self._grows,
+                "append_fallbacks": self._fallbacks}
 
     def _delete(self, u: int, v: int) -> bool:
         s = self._edge2slot.pop((u, v), None)
@@ -406,7 +443,10 @@ class IncrementalOrderer:
     def _median_slot(self, u: int, v: int) -> Optional[int]:
         """Median incident slot of (u, v) via an O(d) numpy partial sort — the
         element at sorted index d // 2, exactly what sorting would pick."""
-        union = self._incident.get(u, set()) | self._incident.get(v, set())
+        a = self._incident.get(u, _NO_SLOTS)
+        b = self._incident.get(v, _NO_SLOTS)
+        self._incident_entries += len(a) + len(b)
+        union = a | b
         if not union:
             return None
         arr = np.fromiter(union, dtype=np.int64, count=len(union))
@@ -436,6 +476,7 @@ class IncrementalOrderer:
             # would land far from its neighbors anyway, so fall back to append.
             alt = self._free_in(append_region) if append_region is not None else None
             if alt is not None:
+                self._fallbacks += 1
                 return alt
         return slot
 
@@ -462,12 +503,14 @@ class IncrementalOrderer:
     def _cache_fill(self, slot: int) -> None:
         a = self._free_cache[slot // self._spr]
         if a is not None:
+            self._free_entries += a.size
             self._free_cache[slot // self._spr] = a[a != slot]
 
     def _cache_freed(self, slot: int) -> None:
         r = slot // self._spr
         a = self._free_cache[r]
         if a is not None:
+            self._free_entries += a.size
             self._free_cache[r] = np.insert(a, int(np.searchsorted(a, slot)), slot)
 
     def _free_in(self, region: int, near: Optional[int] = None) -> Optional[int]:
@@ -478,11 +521,14 @@ class IncrementalOrderer:
         if free.size == 0:
             return None
         if near is None:
+            self._free_entries += 1
             return int(free[0])
+        self._free_entries += free.size
         return int(free[np.argmin(np.abs(free - near))])
 
     def _any_free_slot(self, near: Optional[int]) -> Optional[int]:
         free = np.concatenate([self._free_slots(r) for r in range(self._regions)])
+        self._free_entries += free.size
         if free.size == 0:
             return None
         if near is None:
@@ -1003,18 +1049,29 @@ class IncrementalOrderer:
     def relayout(self, regions: int) -> None:
         """Re-slice the CURRENT incremental order into ``regions`` regions
         (rescale k→k' under ingest: order unchanged, slots re-chunked). Sets
-        ``needs_resync``; ``drain_gather_map`` feeds the on-device compact."""
-        d = self.drift()  # Σ|V_p| scales with the region count, so carry the
-        src_o, dst_o = self.snapshot()  # drift VALUE across the k change
-        old_slot = self._slot_of_edges(src_o, dst_o)
-        self._layout(src_o, dst_o, int(regions))
-        self._map_gather(old_slot, src_o, dst_o)
-        self._finish_relayout()
-        self._baseline_kappa = self._kappa() / max(d, 1e-12)
+        ``needs_resync``; ``drain_gather_map`` feeds the on-device compact.
+        The span ``rescale.relayout`` (counts ``edges``, ``slots``) holds one
+        child per step."""
+        tr = self._spans
+        with tr.span("rescale.relayout") as sp:
+            d = self.drift()  # Σ|V_p| scales with the region count, so carry the
+            with tr.span("rescale.relayout.snapshot"):  # drift VALUE across k
+                src_o, dst_o = self.snapshot()
+            with tr.span("rescale.relayout.slot_map"):
+                old_slot = self._slot_of_edges(src_o, dst_o)
+            with tr.span("rescale.relayout.layout"):
+                self._layout(src_o, dst_o, int(regions), span="rescale.relayout.layout")
+            with tr.span("rescale.relayout.gather_map"):
+                self._map_gather(old_slot, src_o, dst_o)
+                del old_slot  # freeing the per-edge map is part of the step's cost
+            self._finish_relayout()
+            self._baseline_kappa = self._kappa() / max(d, 1e-12)
+            sp.count(edges=int(src_o.shape[0]), slots=self.capacity)
 
     def grow(self, factor: float = 2.0) -> None:
         """Enlarge slots_per_region (same region count, same order, bigger
         gaps) when the array runs out of free slots. Sets ``needs_resync``."""
+        self._grows += 1
         d = self.drift()
         src_o, dst_o = self.snapshot()
         spr = max(self._spr + 1, int(np.ceil(self._spr * factor)))

@@ -43,6 +43,10 @@ re-packing from the host:
   shadow buffers in fixed-capacity chunks — the swap that makes them the
   live pack. Ingest is never blocked longer than that one commit batch.
 
+Their jitted functions are named ``stream_scatter``, ``rescale_compact``,
+``span_repair``, ``full_reorder`` and ``rebuild_splice``, so a profiler trace
+reads each as ``jit_<name>`` whatever the code around it is called.
+
 All five program families live in ONE bounded ``ProgramCache`` LRU under
 kind-prefixed keys, so ``program_cache_size`` bounds every cached program of
 a long-lived engine — and the cache's per-kind hit/miss/eviction counters
@@ -237,20 +241,17 @@ class StreamingEngine:
         }
         # Observability (obs/, DESIGN.md §13). tracer=None falls back to the
         # process-global tracer (disabled by default: spans cost one branch);
-        # metric objects are bound once here so the per-batch hot path does
-        # no registry lookups — against the default NULL registry every bound
-        # object is the shared inert metric.
+        # the orderer's spans go to the same tracer. Metric objects are bound
+        # once here so the per-batch hot path does no registry lookups —
+        # against the default NULL registry every bound object is the shared
+        # inert metric.
         self._tracer = tracer
+        orderer.tracer = tracer
         self.metrics = OM.NULL if metrics_registry is None else metrics_registry
         m = self.metrics
         self._m_ingest_s = m.histogram("stream.ingest.batch_s")
-        self._m_monitor_s = m.histogram("stream.monitor.s")
-        self._m_rung_s = {r: m.histogram(f"stream.rung.{r}_s") for r in ("none", "partial", "full")}
         self._m_updates = {k: m.counter(f"stream.updates.{k}") for k in ("inserted", "deleted", "skipped")}
         self._m_scatter_ops = m.counter("stream.scatter_ops")
-        self._m_resyncs = m.counter("stream.resyncs")
-        self._m_edges = m.gauge("stream.num_edges")
-        self._m_in_flight = m.gauge("stream.rebuilds_in_flight")
         # commit="stream" builds the INITIAL pack shard-by-shard
         # (pack_slots_sharded_stream): each process stages only the slot
         # rows its devices own, never a full host pack — the recovery path's
@@ -260,9 +261,7 @@ class StreamingEngine:
         # upload; "stream" only changes how the FIRST pack is committed.
         self.data = self._upload() if commit == "pack" else self._stream_upload()
         orderer.needs_resync = False
-        self._warm_span_program()
-        self._warm_full_program()
-        self._warm_scatter_programs()
+        self._warm_programs("ingest.warm")
 
     @classmethod
     def from_restored(cls, orderer, mesh=None, **kwargs) -> "StreamingEngine":
@@ -350,11 +349,26 @@ class StreamingEngine:
         with self.tracer.span("ingest.resync"):
             self.orderer.drain_ops()  # ops predate the re-layout; drop them
             self.data = self._upload()
-        self._m_resyncs.inc()
         self.orderer.needs_resync = False
-        self._warm_span_program()  # layout signature may have changed
-        self._warm_full_program()
-        self._warm_scatter_programs()
+        self._warm_programs("ingest.warm")  # layout signature may have changed
+
+    def _warm_programs(self, name: str) -> None:
+        """Warm the span-repair, full-rebuild and scatter programs of the
+        current layout, as the span ``name`` with children ``<name>.span``,
+        ``.full`` and ``.scatter``; each child counts the program-cache
+        misses (compiles) it paid as ``cache_misses``."""
+        tr = self.tracer
+        with tr.span(name):
+            for child, warm in (("span", self._warm_span_program),
+                                ("full", self._warm_full_program),
+                                ("scatter", self._warm_scatter_programs)):
+                with tr.span(f"{name}.{child}") as sp:
+                    misses = self._program_misses()
+                    warm()
+                    sp.count(cache_misses=self._program_misses() - misses)
+
+    def _program_misses(self) -> int:
+        return sum(c["misses"] for c in self._programs.counters.values())
 
     def _warm_span_program(self) -> None:
         """Trace + compile the span-repair program for the CURRENT layout
@@ -496,9 +510,15 @@ class StreamingEngine:
         """Apply one update batch: host slot placement, then the device
         scatter (or a resync when the batch forced a re-layout)."""
         t0 = time.perf_counter()
-        with self.tracer.span("ingest.batch"):
-            with self.tracer.span("ingest.apply"):
+        tr = self.tracer
+        with tr.span("ingest.batch"):
+            with tr.span("ingest.apply") as sp:
                 counts = self.orderer.apply(batch)
+                sp.count(inserts=counts["inserted"], deletes=counts["deleted"],
+                         skipped=counts["skipped"],
+                         incident_entries=counts["incident_entries"],
+                         free_entries=counts["free_entries"], grows=counts["grows"],
+                         append_fallbacks=counts["append_fallbacks"])
             resynced = False
             n_ops = 0
             if self.orderer.needs_resync:
@@ -509,13 +529,13 @@ class StreamingEngine:
                 n_ops = len(ops)
                 if n_ops or deg:
                     self._scatter(ops, deg)
-            jax.block_until_ready(self.data.edges)
+            with tr.span("ingest.ready"):
+                jax.block_until_ready(self.data.edges)
         elapsed = time.perf_counter() - t0
         self._m_ingest_s.observe(elapsed)
         self._m_updates["inserted"].inc(counts["inserted"])
         self._m_updates["deleted"].inc(counts["deleted"])
         self._m_updates["skipped"].inc(counts["skipped"])
-        self._m_edges.set(self.orderer.num_edges)
         if verify:
             self.verify_bit_identity()
         return IngestStats(
@@ -529,16 +549,17 @@ class StreamingEngine:
         )
 
     def _scatter(self, ops, deg: dict) -> None:
-        with self.tracer.span("ingest.scatter"):
-            self._scatter_inner(ops, deg)
+        cap = _next_pow2(max(len(ops), (len(deg) + 1) // 2, _MIN_OP_CAPACITY))
+        with self.tracer.span("ingest.scatter") as sp:
+            self._scatter_inner(ops, deg, cap)
+            sp.count(ops=len(ops), cap=cap)
         self._m_scatter_ops.inc(len(ops))
 
-    def _scatter_inner(self, ops, deg: dict) -> None:
+    def _scatter_inner(self, ops, deg: dict, cap: int) -> None:
         o = self.orderer
         g = SH.graph_axis_size(self.mesh)
         k_pad = self.data.k_pad
         e_cap = int(self.data.edges.shape[1])  # slots_per_region + scratch
-        cap = _next_pow2(max(len(ops), (len(deg) + 1) // 2, _MIN_OP_CAPACITY))
         self._seen_scatter_caps.add(cap)
         # Padding ops target the scratch column (always re-zeroed by the
         # program), so no real slot is ever clobbered by a no-op.
@@ -583,7 +604,7 @@ class StreamingEngine:
         if cached is not None:
             return cached
 
-        def apply(edges, mask, degrees, rows, cols, vals, mvals, verts, dvals):
+        def stream_scatter(edges, mask, degrees, rows, cols, vals, mvals, verts, dvals):
             edges = edges.at[rows, cols].set(vals)
             mask = mask.at[rows, cols].set(mvals)
             degrees = degrees.at[verts].add(dvals)
@@ -596,67 +617,73 @@ class StreamingEngine:
         s_edges, s_mask, s_vert = SH.engine_shardings(mesh)
         jit_kwargs = {"out_shardings": (s_edges, s_mask, s_vert)}
         if self.donate:
-            program = donate_jit(apply, donate_argnums=(0, 1, 2), **jit_kwargs)
+            program = donate_jit(stream_scatter, donate_argnums=(0, 1, 2), **jit_kwargs)
         else:
-            program = jax.jit(apply, **jit_kwargs)
+            program = jax.jit(stream_scatter, **jit_kwargs)
         return self._programs.put(key, program)
 
     # -------------------------------------------------------------- rescale
     def rescale(self, k_new: int, *, verify: bool = False) -> StreamRescaleStats:
         """Re-slice the live stream to ``k_new`` partitions without leaving
         the mesh: the orderer re-chunks the current incremental order (CEP at
-        k_new) and the gather map executes as one compact program."""
+        k_new) and the gather map executes as one compact program. Its steps
+        are spans: ``rescale.sync``, ``rescale.relayout`` (the orderer's),
+        ``rescale.gather_plan``, ``rescale.compact``, ``rescale.warm`` and
+        ``rescale.ready``."""
         t0 = time.perf_counter()
         o = self.orderer
-        # The host may have applied updates since the last device sync (e.g.
-        # orderer.apply called directly): flush them first — the gather map
-        # below describes the post-flush layout, and relayout drops pending
-        # ops.
-        self._sync_pending()
-        # A rescale re-layouts every slot: an in-flight rebuild's snapshot
-        # geometry (and its shadow buffers' shape) is void — abort it.
-        if self._flight is not None:
-            self._abort_rebuild("rescale")
+        tr = self.tracer
+        with tr.span("rescale.sync"):
+            # The host may have applied updates since the last device sync
+            # (e.g. orderer.apply called directly): flush them first — the
+            # gather map below describes the post-flush layout, and relayout
+            # drops pending ops.
+            self._sync_pending()
+            # A rescale re-layouts every slot: an in-flight rebuild's snapshot
+            # geometry (and its shadow buffers' shape) is void — abort it.
+            if self._flight is not None:
+                self._abort_rebuild("rescale")
         g = SH.graph_axis_size(self.mesh)
         k_old, spr_old = o.regions, o.slots_per_region
         old_edges = self.data.edges
         o.relayout(int(k_new))
-        gm = o.drain_gather_map()
-        spr_new = o.slots_per_region
-        e_cap_old = int(old_edges.shape[1])
-        e_cap_new = spr_new + 1
-        k_pad_new = SH.padded_partition_count(int(k_new), g)
+        with tr.span("rescale.gather_plan"):
+            gm = o.drain_gather_map()
+            spr_new = o.slots_per_region
+            e_cap_old = int(old_edges.shape[1])
+            e_cap_new = spr_new + 1
+            k_pad_new = SH.padded_partition_count(int(k_new), g)
 
-        new_slots = np.flatnonzero(gm >= 0)
-        old_slots = gm[new_slots]
-        new_regions = new_slots // spr_new
-        old_regions = old_slots // spr_old
-        src_row = np.zeros((k_pad_new, e_cap_new), dtype=np.int32)
-        src_col = np.zeros((k_pad_new, e_cap_new), dtype=np.int32)
-        validf = np.zeros((k_pad_new, e_cap_new), dtype=np.float32)
-        dst_rows = _rows_of_regions(new_regions, int(k_new), g)
-        dst_cols = new_slots % spr_new
-        src_row[dst_rows, dst_cols] = _rows_of_regions(old_regions, k_old, g)
-        src_col[dst_rows, dst_cols] = old_slots % spr_old
-        validf[dst_rows, dst_cols] = 1.0
+            new_slots = np.flatnonzero(gm >= 0)
+            old_slots = gm[new_slots]
+            new_regions = new_slots // spr_new
+            old_regions = old_slots // spr_old
+            src_row = np.zeros((k_pad_new, e_cap_new), dtype=np.int32)
+            src_col = np.zeros((k_pad_new, e_cap_new), dtype=np.int32)
+            validf = np.zeros((k_pad_new, e_cap_new), dtype=np.float32)
+            dst_rows = _rows_of_regions(new_regions, int(k_new), g)
+            dst_cols = new_slots % spr_new
+            src_row[dst_rows, dst_cols] = _rows_of_regions(old_regions, k_old, g)
+            src_col[dst_rows, dst_cols] = old_slots % spr_old
+            validf[dst_rows, dst_cols] = 1.0
 
-        moved = int(np.count_nonzero(new_regions != old_regions))
-        cross = int(
-            np.count_nonzero(
-                (new_regions != old_regions) & (new_regions % g != old_regions % g)
+            moved = int(np.count_nonzero(new_regions != old_regions))
+            cross = int(
+                np.count_nonzero(
+                    (new_regions != old_regions) & (new_regions % g != old_regions % g)
+                )
             )
-        )
-        procs = SH.device_process_map(self.mesh)
-        xproc = int(
-            np.count_nonzero(
-                (new_regions != old_regions)
-                & (procs[new_regions % g] != procs[old_regions % g])
+            procs = SH.device_process_map(self.mesh)
+            xproc = int(
+                np.count_nonzero(
+                    (new_regions != old_regions)
+                    & (procs[new_regions % g] != procs[old_regions % g])
+                )
             )
-        )
         program = self._compact_program(
             (int(old_edges.shape[0]), e_cap_old, k_pad_new, e_cap_new, self.mesh)
         )
-        with self.tracer.span("rescale.compact"):
+        with tr.span("rescale.compact"):
             edges, mask = program(
                 old_edges,
                 self._host_operand(src_row),
@@ -678,13 +705,11 @@ class StreamingEngine:
         # The k_new layout is a new span/full/scatter-program signature:
         # compile them here, inside the rescale's reported latency, not
         # inside the first escalation or ingest of the new layout.
-        self._warm_span_program()
-        self._warm_full_program()
-        self._warm_scatter_programs()
-        jax.block_until_ready(self.data.edges)
+        self._warm_programs("rescale.warm")
+        with tr.span("rescale.ready"):
+            jax.block_until_ready(self.data.edges)
         elapsed = time.perf_counter() - t0
         m = self.metrics
-        m.histogram("stream.rescale.s").observe(elapsed)
         m.counter("stream.rescale.cross_device_bytes").inc(cross * EDGE_BYTES)
         m.counter("stream.rescale.cross_process_bytes").inc(xproc * EDGE_BYTES)
         if verify:
@@ -708,7 +733,7 @@ class StreamingEngine:
             return cached
         mesh = key[-1]
 
-        def compact(edges_old, src_row, src_col, validf):
+        def rescale_compact(edges_old, src_row, src_col, validf):
             gathered = edges_old[src_row, src_col]  # (k_pad_new, e_cap_new, 2)
             new_edges = gathered * validf[..., None].astype(gathered.dtype)
             return new_edges, validf
@@ -716,9 +741,9 @@ class StreamingEngine:
         s_edges, s_mask, _ = SH.engine_shardings(mesh)
         jit_kwargs = {"out_shardings": (s_edges, s_mask)}
         if self.donate:
-            program = donate_jit(compact, donate_argnums=(0,), **jit_kwargs)
+            program = donate_jit(rescale_compact, donate_argnums=(0,), **jit_kwargs)
         else:
-            program = jax.jit(compact, **jit_kwargs)
+            program = jax.jit(rescale_compact, **jit_kwargs)
         return self._programs.put(("compact",) + key, program)
 
     # ------------------------------------------------------------ escalation
@@ -741,12 +766,8 @@ class StreamingEngine:
         t0 = time.perf_counter()
         with self.tracer.span("rung.monitor"):
             rung = self._monitor_inner()
-        elapsed = time.perf_counter() - t0
         self.rung_counts[rung] += 1
-        self.rung_s[rung] += elapsed
-        self._m_monitor_s.observe(elapsed)
-        self._m_rung_s[rung].observe(elapsed)
-        self._m_in_flight.set(self.rebuilds_in_flight)
+        self.rung_s[rung] += time.perf_counter() - t0
         return rung
 
     def _monitor_inner(self) -> str:
@@ -1038,7 +1059,7 @@ class StreamingEngine:
         if cached is not None:
             return cached
 
-        def splice(edges, mask, rows, cols, vals, mvals):
+        def rebuild_splice(edges, mask, rows, cols, vals, mvals):
             edges = edges.at[rows, cols].set(vals)
             mask = mask.at[rows, cols].set(mvals)
             # Scratch column absorbs the padded no-op writes (same contract
@@ -1052,9 +1073,9 @@ class StreamingEngine:
         if self.donate:
             # Donating is safe HERE: the inputs are the shadow buffers (or a
             # previous chunk's output), which nothing else references.
-            program = donate_jit(splice, donate_argnums=(0, 1), **jit_kwargs)
+            program = donate_jit(rebuild_splice, donate_argnums=(0, 1), **jit_kwargs)
         else:
-            program = jax.jit(splice, **jit_kwargs)
+            program = jax.jit(rebuild_splice, **jit_kwargs)
         return self._programs.put(key, program)
 
     def _full_key(self, mode: str, k: int, k_pad: int, e_cap: int, mesh):
@@ -1083,7 +1104,7 @@ class StreamingEngine:
             return cached
         num_vertices = self.num_vertices
 
-        def rebuild(edges, mask, rows, cand, *rest):
+        def full_reorder(edges, mask, rows, cand, *rest):
             blk_e = edges[rows]  # (k, e_cap, 2) — every region's row
             blk_m = mask[rows]
             u = blk_e[:, :spr, 0].reshape(cap)
@@ -1128,7 +1149,7 @@ class StreamingEngine:
 
         s_edges, s_mask, _ = SH.engine_shardings(mesh)
         # No donation by design — see the docstring.
-        program = jax.jit(rebuild, out_shardings=(s_edges, s_mask))
+        program = jax.jit(full_reorder, out_shardings=(s_edges, s_mask))
         return self._programs.put(key, program)
 
     def _partial_rung(self) -> None:
@@ -1226,7 +1247,7 @@ class StreamingEngine:
             return cached
         num_vertices = self.num_vertices
 
-        def repair(edges, mask, rows, cand, use_cand):
+        def span_repair(edges, mask, rows, cand, use_cand):
             blk_e = edges[rows]  # (s, e_cap, 2) — span rows only
             blk_m = mask[rows]
             u = blk_e[:, :spr, 0].reshape(cap)
@@ -1271,9 +1292,9 @@ class StreamingEngine:
         s_edges, s_mask, _ = SH.engine_shardings(mesh)
         jit_kwargs = {"out_shardings": (s_edges, s_mask)}
         if self.donate:
-            program = donate_jit(repair, donate_argnums=(0, 1), **jit_kwargs)
+            program = donate_jit(span_repair, donate_argnums=(0, 1), **jit_kwargs)
         else:
-            program = jax.jit(repair, **jit_kwargs)
+            program = jax.jit(span_repair, **jit_kwargs)
         return self._programs.put(key, program)
 
     def rf_vs_oracle(self, k: Optional[int] = None) -> tuple[float, float]:
