@@ -193,12 +193,13 @@ class IncrementalOrderer:
 
     # ---------------------------------------------------------------- layout
     def _layout(self, src_o: np.ndarray, dst_o: np.ndarray, regions: int,
-                spr: Optional[int] = None, span: str = "layout") -> None:
+                spr: Optional[int] = None, span: str = "layout") -> np.ndarray:
         """(Re)build the slot array from an ordered list: CEP chunk at
         k=regions, each chunk's edges spread evenly over its region's slots so
         gaps are interleaved (PMA style) and early inserts never shift. Its
         steps are spans ``<span>.fill``, ``.edge_map``, ``.region_counts``
-        and ``.incident``."""
+        and ``.incident``. Returns the new slot of each input edge (ascending,
+        since the spread keeps the input order)."""
         e = int(src_o.shape[0])
         if spr is None:
             raw = max(2, int(np.ceil(e * (1.0 + self.config.slack) / regions)))
@@ -240,7 +241,7 @@ class IncrementalOrderer:
         self._free_cache: list = [None] * int(regions)
         self._gather_from = None  # new slot ← old slot; only relayout builds it
         if e == 0:
-            return
+            return np.zeros(0, dtype=np.int64)
         # Vectorized fill (the same CEP spread the device splice computes):
         # the per-edge dict/set bookkeeping below is bulk-built — this runs on
         # every full rebuild and relayout, so it must not out-cost geo_order.
@@ -273,6 +274,7 @@ class IncrementalOrderer:
             self._incident = {
                 w: set(sslots[a:b]) for w, a, b in zip(ws, starts, ends)
             }
+        return slots
 
     def _set_baseline(self) -> None:
         """Record the current normalized objective as 'fresh-GEO quality'.
@@ -1057,13 +1059,16 @@ class IncrementalOrderer:
             d = self.drift()  # Σ|V_p| scales with the region count, so carry the
             with tr.span("rescale.relayout.snapshot"):  # drift VALUE across k
                 src_o, dst_o = self.snapshot()
-            with tr.span("rescale.relayout.slot_map"):
-                old_slot = self._slot_of_edges(src_o, dst_o)
+                # The snapshot lists edges in ascending old-slot order, so
+                # edge j came from old_occupied[j].
+                old_occupied = np.flatnonzero(self.slot_valid)
             with tr.span("rescale.relayout.layout"):
-                self._layout(src_o, dst_o, int(regions), span="rescale.relayout.layout")
-            with tr.span("rescale.relayout.gather_map"):
-                self._map_gather(old_slot, src_o, dst_o)
-                del old_slot  # freeing the per-edge map is part of the step's cost
+                slots = self._layout(src_o, dst_o, int(regions), span="rescale.relayout.layout")
+            with tr.span("rescale.relayout.gather_map") as gsp:
+                gm = np.full(self.capacity, -1, dtype=np.int64)
+                gm[slots] = old_occupied
+                self._gather_from = gm
+                gsp.count(pairs=int(slots.size))
             self._finish_relayout()
             self._baseline_kappa = self._kappa() / max(d, 1e-12)
             sp.count(edges=int(src_o.shape[0]), slots=self.capacity)
@@ -1078,20 +1083,6 @@ class IncrementalOrderer:
         self._layout(src_o, dst_o, self._regions, spr=spr)
         self._finish_relayout()
         self._baseline_kappa = self._kappa() / max(d, 1e-12)
-
-    def _slot_of_edges(self, src_o: np.ndarray, dst_o: np.ndarray) -> dict:
-        return {
-            (int(a), int(b)): self._edge2slot[(int(a), int(b))]
-            for a, b in zip(src_o.tolist(), dst_o.tolist())
-        }
-
-    def _map_gather(self, old_slot: dict, src_o: np.ndarray, dst_o: np.ndarray) -> None:
-        gm = np.full(self.capacity, -1, dtype=np.int64)
-        occupied = np.flatnonzero(self.slot_valid)
-        for s_ in occupied.tolist():
-            key = (int(self.slot_src[s_]), int(self.slot_dst[s_]))
-            gm[s_] = old_slot[key]
-        self._gather_from = gm
 
     def _finish_relayout(self) -> None:
         self._ops.clear()

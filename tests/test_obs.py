@@ -432,8 +432,8 @@ def _traced_stream(tracer):
 RESCALE_TREE = {
     "rescale.event": ["rescale.sync", "rescale.relayout", "rescale.gather_plan",
                       "rescale.compact", "rescale.warm", "rescale.ready"],
-    "rescale.relayout": ["rescale.relayout.snapshot", "rescale.relayout.slot_map",
-                         "rescale.relayout.layout", "rescale.relayout.gather_map"],
+    "rescale.relayout": ["rescale.relayout.snapshot", "rescale.relayout.layout",
+                         "rescale.relayout.gather_map"],
     "rescale.relayout.layout": [f"rescale.relayout.layout.{s}" for s in
                                 ("fill", "edge_map", "region_counts", "incident")],
     "rescale.warm": ["rescale.warm.span", "rescale.warm.full", "rescale.warm.scatter"],

@@ -587,6 +587,55 @@ def test_drift_carried_across_relayouts_reset_only_by_full_rebuild(ordered):
     assert o.drift() == pytest.approx(1.0, abs=1e-9)
 
 
+def _prepared_orderer(ordered, prep):
+    """An orderer at k=4 with tombstones and inserts from a stream, then
+    ``prep``: ``grow`` re-spreads it wider, ``span_rewrite`` re-orders one
+    span in place, ``from_slots`` rebuilds it from its raw slot arrays."""
+    g, o = make_orderer(ordered)
+    stream = SyntheticStream(g, batch_size=64, seed=17)
+    for _ in range(5):
+        o.apply(stream.batch())
+    if prep == "grow":
+        o.grow()
+    elif prep == "span_rewrite":
+        assert o.partial_reorder() > 0
+    elif prep == "from_slots":
+        o = IncrementalOrderer.from_slots(
+            o.slot_src, o.slot_dst, o.slot_valid, g.num_vertices, regions=o.regions
+        )
+    assert not o.slot_valid.all()  # holes to skip
+    return o
+
+
+@pytest.mark.parametrize("k_new", [6, 3, 1])
+@pytest.mark.parametrize("prep", ["holes", "grow", "span_rewrite", "from_slots"])
+def test_relayout_gather_map_matches_edge_dict_reference(ordered, prep, k_new):
+    """The gather map ``relayout`` builds from the two slot arrays equals, slot
+    for slot, the per-edge construction it replaced: the old slot of each edge
+    looked up by its (u, v) key."""
+    from repro.obs import trace as OT
+
+    o = _prepared_orderer(ordered, prep)
+    old_src, old_dst = o.slot_src.copy(), o.slot_dst.copy()
+    old_slot = {e: int(s) for e, s in o._edge2slot.items()}
+    o.tracer = OT.Tracer(capacity=256)
+    o.relayout(k_new)
+    gm = o.drain_gather_map()
+
+    ref = np.full(o.capacity, -1, dtype=np.int64)
+    for s in np.flatnonzero(o.slot_valid).tolist():
+        ref[s] = old_slot[(int(o.slot_src[s]), int(o.slot_dst[s]))]
+    np.testing.assert_array_equal(gm, ref)
+    occ = gm >= 0
+    np.testing.assert_array_equal(occ, o.slot_valid)
+    np.testing.assert_array_equal(old_src[gm[occ]], o.slot_src[occ])
+    np.testing.assert_array_equal(old_dst[gm[occ]], o.slot_dst[occ])
+
+    by = {s.name: s for s in o.tracer.spans()}
+    assert by["rescale.relayout.gather_map"].counts == {"pairs": o.num_edges}
+    assert by["rescale.relayout"].counts["edges"] == o.num_edges
+
+
 def test_per_rung_counters_and_timings_recorded_on_ingest_events(ordered):
     g, src, dst = ordered
     o = IncrementalOrderer(
