@@ -67,6 +67,29 @@ def lost_partition(cell):
     eng._compact_program = compact_program
 
 
+def no_exchange(cell):
+    """Every compaction of a scale event gathers on each chip from that
+    chip's own rows only: edges whose partition moves to another chip are
+    dropped, as a compact with its exchange between chips left out would."""
+    import numpy as np
+
+    eng = cell.eng
+    real = eng._compact_program
+
+    def compact_program(key):
+        program = real(key)
+        chips = eng.mesh.devices.size
+
+        def compact(edges_old, src_row, src_col, validf):
+            src = np.asarray(src_row)
+            dst = np.arange(src.shape[0])[:, None] // (src.shape[0] // chips)
+            local = src // (edges_old.shape[0] // chips) == dst
+            return program(edges_old, src_row, src_col,
+                           eng._host_operand(np.asarray(validf) * local))
+        return compact
+    eng._compact_program = compact_program
+
+
 def unchanged_search(cell):
     """A search returns its initial state: only the root reached."""
     import jax.numpy as jnp

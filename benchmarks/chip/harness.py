@@ -5,9 +5,16 @@ adding files:
 
 * ``BENCHMARK.json`` (the checkout's root) names the cell's configuration,
   traffic mix and chips, and every metric;
-* ``configs/<config>.json`` holds the deployment: the graph's generator
-  parameters, the partition count, and every option of the program's orderer,
-  streaming engine, controller and preprocess, as run;
+* ``configs/<config>.json`` holds the deployment: the graph's generator and
+  its parameters, the partition count, the commit, and every option of the
+  program's orderer, streaming engine, controller and preprocess, as run;
+* ``generators/<generator>.py`` makes the configuration's graph (its
+  ``graph.generator``): ``edges(graph)`` returns the (E, 2) int64 base edges
+  from the configuration's ``graph`` group;
+* ``commits/<commit>.py`` builds what the program is handed at commit (the
+  configuration's ``commit``, ``ordered`` where it names none):
+  ``orderer(cell, ordered)`` returns the orderer of the GEO-ordered edges
+  that the streaming engine is built on;
 * ``traffic/<mix>.json`` holds the mix's parameters: a ``round`` of
   ``[kind, count]`` steps repeated until the window closes, each kind's
   parameters under its own key, the ``checks`` that decide ``correct``, and
@@ -42,7 +49,6 @@ import time
 
 import numpy as np
 
-import graphgen
 import reference
 import xplane
 
@@ -81,11 +87,17 @@ def metric_specs(bench: dict, workload: str, trace: bool) -> list:
     return [m for m in specs if "workloads" not in m or workload in m["workloads"]]
 
 
-def module(root: str, folder: str, name: str):
-    """The benchmark's module ``<folder>/<name>.py``."""
+def module_path(root: str, folder: str, name: str) -> str:
+    """The path of the benchmark's module ``<folder>/<name>.py``."""
     path = os.path.join(root, BENCH_REL, folder, name + ".py")
     if not os.path.exists(path):
         raise BenchError(f"no {folder}/{name}.py in {os.path.join(root, BENCH_REL)}")
+    return path
+
+
+def module(root: str, folder: str, name: str):
+    """The benchmark's module ``<folder>/<name>.py``."""
+    path = module_path(root, folder, name)
     spec = importlib.util.spec_from_file_location(f"{folder}_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
@@ -98,11 +110,19 @@ def reader(root: str, name: str):
 
 
 # --------------------------------------------------------------- the order
-def _source_hash(root: str, config_path: str) -> str:
-    """Key of the order cache: the configuration file, the generator and
-    every file of the program."""
+def commit_name(config: dict) -> str:
+    """The configuration's commit module; ``ordered`` where it names none."""
+    return config.get("commit", "ordered")
+
+
+def _source_hash(root: str, config: dict, config_path: str) -> str:
+    """Key of the order cache: the configuration file, the graph generator
+    (``graphgen.py`` and the configuration's generator module), its commit
+    module and every file of the program."""
     h = hashlib.sha256()
-    paths = [config_path, os.path.join(root, BENCH_REL, "graphgen.py")]
+    paths = [config_path, os.path.join(root, BENCH_REL, "graphgen.py"),
+             module_path(root, "generators", config["graph"]["generator"]),
+             module_path(root, "commits", commit_name(config))]
     src = os.path.join(root, "src", "repro")
     for d, dirs, files in os.walk(src):
         dirs[:] = sorted(x for x in dirs if x != "__pycache__")
@@ -117,15 +137,16 @@ def _source_hash(root: str, config_path: str) -> str:
 def graph_and_order(root: str, name: str, config: dict, config_path: str):
     """(base edges, GEO-ordered edges, cached?): the generator's graph and the
     program's preprocess of it, cached in the checkout under a key that any
-    change of the configuration, the generator or the program renews."""
-    key = _source_hash(root, config_path)
+    change of the configuration, the generator, the commit or the program
+    renews."""
+    key = _source_hash(root, config, config_path)
     d = os.path.join(root, BENCH_REL, ".cache", "order")
     path = os.path.join(d, f"{name}.{key}.npz")
     if os.path.exists(path):
         with np.load(path) as z:
             return z["base"].astype(np.int64), z["ordered"].astype(np.int64), True
     g = config["graph"]
-    base = graphgen.graph_edges(g["scale"], g["edge_factor"], g["initiator"], g["seed"])
+    base = module(root, "generators", g["generator"]).edges(g)
     from repro.core import hier_order as HO
 
     p = config["preprocess"]
@@ -228,8 +249,7 @@ class Cell:
 
         from repro.elastic import controller as EC
         from repro.launch import mesh as MM
-        from repro.stream import IncrementalOrderer, StreamingEngine
-        from repro.stream.incremental import StreamConfig
+        from repro.stream import StreamingEngine
 
         self.jax = jax
         self.config, self.mix, self.seed, self.seconds = config, mix, seed, seconds
@@ -241,8 +261,7 @@ class Cell:
         self.snapshots = []  # (log length, expected k, pack's k, device copies)
         t = time.perf_counter()
         self.mesh = MM.make_graph_mesh(chips)
-        orderer = IncrementalOrderer(ordered[:, 0], ordered[:, 1], self.v, regions=self.k,
-                                     config=StreamConfig(**config["orderer"]))
+        orderer = module(root, "commits", commit_name(config)).orderer(self, ordered)
         self.eng = StreamingEngine(orderer, self.mesh, tracer=tracer, **config["engine"])
         self.clock = FakeClock()
         self.ctl = EC.ElasticController(self.k, clock=self.clock, tracer=tracer,

@@ -236,13 +236,52 @@ def quiet_root(tmp_path_factory):
     return tiny_root(tmp_path_factory.mktemp("quiet"), quiet=True)
 
 
+BENCH = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+CELLS = [w["name"] for w in BENCH["workloads"]]
+CHIPS = {w["name"]: int(w["chips"]) for w in BENCH["workloads"]}
+
+
+def seconds(workload) -> float:
+    """A window long enough for a rescale cell's events."""
+    traffic = {w["name"]: w["traffic"] for w in BENCH["workloads"]}.get(workload)
+    if traffic is None:
+        return 0.3
+    mix = harness.load_json(os.path.join(ROOT, harness.BENCH_REL, "traffic", traffic + ".json"))
+    return 1.5 if "event" in mix else 0.3
+
+
+def in_child(code: str, chips: int) -> list:
+    """Run ``code`` in a child forced to ``chips`` host devices; returns the
+    objects it printed after ``RESULT``."""
+    from repro.launch.multihost import force_host_device_flags
+
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = force_host_device_flags(chips, env.get("XLA_FLAGS", ""))
+    env["JAX_PLATFORMS"] = "cpu"
+    prelude = "import json, sys, time; sys.path[:0] = [%r, %r]\n" % (HERE, os.path.join(ROOT, "src"))
+    r = subprocess.run([sys.executable, "-c", prelude + code], capture_output=True, text=True,
+                       env=env, cwd=ROOT, timeout=600)
+    assert r.returncode == 0, f"STDOUT:\n{r.stdout[-4000:]}\nSTDERR:\n{r.stderr[-4000:]}"
+    return [json.loads(x[len("RESULT "):]) for x in r.stdout.splitlines()
+            if x.startswith("RESULT ")]
+
+
 def run(root, workload, *, trace=False, on_window=None, seed=2**31 + 11):
-    """One in-process run; the rescale window is long enough for events."""
+    """One run: in this process, or, for a cell on more chips than this
+    process has, in a child forced to that many host devices."""
     import time
 
-    seconds = 1.5 if workload.endswith("rescale") else 0.3
-    return harness.run_cell(root, workload, seed, seconds, trace, t_start=time.perf_counter(),
-                            require_tpu=False, on_window=on_window)
+    chips = CHIPS.get(workload, 1)
+    if chips > 1:
+        fault = "None" if on_window is None else f"faults.{on_window.__name__}"
+        out, = in_child(
+            "import faults, harness\n"
+            f"out = harness.run_cell({root!r}, {workload!r}, {seed}, {seconds(workload)}, "
+            f"{trace}, t_start=time.perf_counter(), require_tpu=False, on_window={fault})\n"
+            "print('RESULT ' + json.dumps(out))\n", chips)
+        return out
+    return harness.run_cell(root, workload, seed, seconds(workload), trace,
+                            t_start=time.perf_counter(), require_tpu=False, on_window=on_window)
 
 
 def last_line(out, capsys) -> dict:
@@ -250,14 +289,12 @@ def last_line(out, capsys) -> dict:
     return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
 
 
-CELLS = [w["name"] for w in harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))["workloads"]]
-
-
 @pytest.mark.parametrize("workload", CELLS)
 def test_every_mix_runs_a_whole_cell_at_a_tiny_size(root, workload, capsys):
     line = last_line(run(root, workload), capsys)
     assert set(line) == CONTRACT_KEYS | {"checks"} and list(line)[-1] == "checks"
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
+    assert line["device"]["count"] == CHIPS[workload]
     bench = harness.load_json(os.path.join(root, "BENCHMARK.json"))
     want = {m["name"] for m in harness.metric_specs(bench, workload, trace=False)}
     assert set(line["metrics"]) == want and "setup_s" in want and len(want) >= 2
@@ -346,6 +383,129 @@ def test_a_new_operation_kind_and_its_check_are_found_by_name(tmp_path, capsys):
     assert line["metrics"]["counts_per_s"]["value"] > 0 and "setup_s" in line["metrics"]
 
 
+GEN_GRID = """\"\"\"grid: a square lattice of 2**scale vertices, edges in row order.\"\"\"
+import numpy as np
+
+
+def edges(graph):
+    side = 1 << (graph["scale"] // 2)
+    ids = np.arange(side * side).reshape(side, side)
+    right = np.stack([ids[:, :-1].ravel(), ids[:, 1:].ravel()], axis=1)
+    down = np.stack([ids[:-1, :].ravel(), ids[1:, :].ravel()], axis=1)
+    return np.concatenate([right, down]).astype(np.int64)
+"""
+COMMIT_COUNTED = """\"\"\"counted: the ordered commit, and a note of the edges it was handed.\"\"\"
+import os
+
+from repro.stream import IncrementalOrderer
+from repro.stream.incremental import StreamConfig
+
+
+def orderer(cell, ordered):
+    with open(os.path.join(os.path.dirname(__file__), "committed.txt"), "w") as f:
+        f.write(str(ordered.shape[0]))
+    return IncrementalOrderer(ordered[:, 0], ordered[:, 1], cell.v, regions=cell.k,
+                              config=StreamConfig(**cell.config["orderer"]))
+"""
+
+
+def add_cell_on_config(r, conf: dict) -> str:
+    """Write ``conf`` as configuration ``conf-test`` and a one-chip BFS cell
+    on it; returns the cell's name."""
+    path = os.path.join(harness.BENCH_REL, "configs", "conf-test.json")
+    with open(os.path.join(r, path), "w") as f:
+        json.dump(conf, f)
+    bench_path = os.path.join(r, "BENCHMARK.json")
+    bench = harness.load_json(bench_path)
+    bench["configs"].append({"name": "conf-test", "source": "test", "file": path,
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "conf-cell", "config": "conf-test",
+                               "traffic": "bfs", "chips": 1, "why": "test"})
+    for m in bench["end_to_end"]:
+        if m["name"] == "bfs_teps":
+            m["workloads"].append("conf-cell")
+    with open(bench_path, "w") as f:
+        json.dump(bench, f)
+    return "conf-cell"
+
+
+def test_a_new_generator_and_commit_are_found_by_name(tmp_path, capsys):
+    """A deployment whose graph generator (``generators/grid.py``) and commit
+    (``commits/counted.py``) no file had before: new files only, plus the
+    configuration's entry in BENCHMARK.json; its searches are checked
+    against BFS over the lattice."""
+    r = tiny_root(tmp_path)
+    d = os.path.join(r, harness.BENCH_REL)
+    for folder, name, text in (("generators", "grid", GEN_GRID),
+                               ("commits", "counted", COMMIT_COUNTED)):
+        with open(os.path.join(d, folder, name + ".py"), "w") as f:
+            f.write(text)
+    conf = harness.load_json(os.path.join(d, "configs", "gap-urand-s19.json"))
+    conf["graph"]["generator"], conf["commit"] = "grid", "counted"
+    line = last_line(run(r, add_cell_on_config(r, conf)), capsys)
+    assert line["correct"] is True and line["checks"]["bfs_wrong"]["value"] == 0
+    with open(os.path.join(d, "commits", "committed.txt")) as f:
+        assert int(f.read()) == 2 * 16 * 15  # the 16 x 16 lattice's edges, each once
+
+
+def test_an_unknown_generator_is_refused(tmp_path):
+    """A configuration whose generator has no file fails; no other graph
+    stands in for it."""
+    r = tiny_root(tmp_path)
+    conf = harness.load_json(os.path.join(r, harness.BENCH_REL, "configs", "gap-kron-s19.json"))
+    conf["graph"]["generator"] = "lattice"
+    with pytest.raises(harness.BenchError, match="generators/lattice.py"):
+        run(r, add_cell_on_config(r, conf))
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_generator_modules_give_graphgen_bytes_order_and_pack(root, config):
+    """Each configuration's generator module returns what ``graphgen`` gives,
+    byte for byte; the cached GEO order is the preprocess of those edges;
+    and the ``ordered`` commit hands the engine the pack that an orderer
+    built directly from them gives."""
+    import jax
+    from repro.core import hier_order as HO
+    from repro.launch import mesh as MM
+    from repro.stream import IncrementalOrderer, StreamingEngine
+    from repro.stream.incremental import StreamConfig
+
+    path = os.path.join(root, harness.BENCH_REL, "configs", config + ".json")
+    conf = harness.load_json(path)
+    g = conf["graph"]
+    want = graphgen.graph_edges(g["scale"], g["edge_factor"], g["initiator"], g["seed"])
+    got = harness.module(root, "generators", g["generator"]).edges(g)
+    assert got.dtype == want.dtype == np.int64 and got.tobytes() == want.tobytes()
+    base, ordered, _ = harness.graph_and_order(root, config, conf, path)
+    p = conf["preprocess"]
+    order, _ = HO.hier_order_edges(want, 1 << g["scale"], HO.HierConfig(
+        **{k: v for k, v in p.items() if k != "sample_stride"}), sample=want[:: p["sample_stride"]])
+    assert base.tobytes() == want.tobytes()
+    assert ordered.tobytes() == np.asarray(order, np.int64).tobytes()
+
+    class Cell:  # what the commit reads of ``harness.Cell``
+        v, k, config = 1 << g["scale"], int(conf["partitions"]), conf
+
+    mesh = MM.make_graph_mesh(1)
+    packs = [StreamingEngine(o, mesh, **conf["engine"]).data for o in (
+        harness.module(root, "commits", harness.commit_name(conf)).orderer(Cell, ordered),
+        IncrementalOrderer(ordered[:, 0], ordered[:, 1], Cell.v, regions=Cell.k,
+                           config=StreamConfig(**conf["orderer"])))]
+    for a, b in zip(*((x.edges, x.mask, x.degrees) for x in packs)):
+        assert np.asarray(jax.device_get(a)).tobytes() == np.asarray(jax.device_get(b)).tobytes()
+
+
+@pytest.mark.parametrize("folder,name", [("generators", "kronecker"), ("commits", "ordered")])
+def test_order_cache_key_follows_the_generator_and_commit_modules(tmp_path, folder, name):
+    r = tiny_root(tmp_path)
+    path = os.path.join(r, harness.BENCH_REL, "configs", "gap-kron-s19.json")
+    conf = harness.load_json(path)
+    before = harness._source_hash(r, conf, path)
+    with open(harness.module_path(r, folder, name), "a") as f:
+        f.write("# changed\n")
+    assert harness._source_hash(r, conf, path) != before
+
+
 def test_updates_are_counted_from_what_the_benchmark_sent(quiet_root, capsys):
     """An ingest that acknowledges each batch and applies none still reads
     the updates sent, and the pack check reads it not correct."""
@@ -382,6 +542,9 @@ def test_command_exits_nonzero_without_a_tpu(tmp_path, alone):
     ("kron19-ingest", faults.altered_scatter),
     ("kron19-rescale", faults.unchanged_rescale),
     ("kron19-rescale", faults.lost_partition),
+    ("kron19-rescale-x4", faults.unchanged_rescale),
+    ("kron19-rescale-x4", faults.lost_partition),
+    ("kron19-rescale-x4", faults.no_exchange),
     ("urand19-bfs", faults.unchanged_search),
     ("urand19-bfs", faults.altered_search),
     ("urand19-bfs", faults.early_stop),
@@ -392,14 +555,20 @@ def test_a_broken_timed_path_reads_not_correct(quiet_root, workload, fault, caps
     assert any(c["value"] > c["limit"] for c in line["checks"].values())
 
 
-@pytest.mark.parametrize("workload", ["kron19-rescale", "kron19-ingest", "urand19-bfs"])
+@pytest.mark.parametrize("workload", ["kron19-rescale", "kron19-ingest", "urand19-bfs",
+                                      "kron19-rescale-x4"])
 def test_control_reads_not_correct_where_sound_seeds_read_correct(quiet_root, workload):
     """The control of ``control.py`` at a tiny size: two sound windows on
     seeds of their own, then two with the cell's control fault planted."""
-    import control
+    args = (quiet_root, workload, seconds(workload), [2**33 + 1, 5], [2**33 + 2, 6])
+    if CHIPS[workload] > 1:
+        recs = in_child("import control\n"
+                        f"for r in control.readings(*{args!r}, require_tpu=False):\n"
+                        "    print('RESULT ' + json.dumps(r))\n", CHIPS[workload])
+    else:
+        import control
 
-    recs = list(control.readings(quiet_root, workload, 1.5 if workload.endswith("rescale") else 0.3,
-                                 [2**33 + 1, 5], [2**33 + 2, 6], require_tpu=False))
+        recs = list(control.readings(*args, require_tpu=False))
     assert [r["control"] is None for r in recs] == [True, True, False, False]
     assert [r["correct"] for r in recs] == [True, True, False, False]
     assert all(max(r["checks"].values()) > 0 for r in recs[2:])

@@ -18,10 +18,10 @@ import xplane  # noqa: E402
 from test_chip_benchmark import run, tiny_root  # noqa: E402
 
 BENCH = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-NEW = {"relayout_ms": "kron19-rescale", "rescale_warm_ms": "kron19-rescale",
-       "rescale_compiles": "kron19-rescale", "scatter_device_ms": "kron19-ingest",
-       "incident_entries_per_insert": "kron19-ingest",
-       "free_entries_per_update": "kron19-ingest"}
+RESCALE = ["kron19-rescale", "kron19-rescale-x4"]
+NEW = {"relayout_ms": RESCALE, "rescale_warm_ms": RESCALE, "rescale_compiles": RESCALE,
+       "scatter_device_ms": ["kron19-ingest"], "incident_entries_per_insert": ["kron19-ingest"],
+       "free_entries_per_update": ["kron19-ingest"]}
 
 
 @pytest.fixture(scope="module")
@@ -31,25 +31,25 @@ def root(tmp_path_factory):
 
 def test_each_new_metric_is_declared_for_its_cell():
     specs = {m["name"]: m for m in BENCH["per_layer"]}
-    for name, cell in NEW.items():
-        assert specs[name]["workloads"] == [cell]
+    for name, cells in NEW.items():
+        assert specs[name]["workloads"] == cells
         assert harness.reader(ROOT, name) is not None
 
 
-@pytest.mark.parametrize("workload", ["kron19-rescale", "kron19-ingest"])
+@pytest.mark.parametrize("workload", ["kron19-rescale", "kron19-ingest", "kron19-rescale-x4"])
 def test_a_traced_run_reads_every_new_span_metric(root, workload):
     out = run(root, workload, trace=True)
     assert out["correct"] is True
     got = out["metrics"]
-    for name, cell in NEW.items():
+    for name, cells in NEW.items():
         spec = next(m for m in BENCH["per_layer"] if m["name"] == name)
-        if cell != workload:
+        if workload not in cells:
             assert name not in got
         elif spec["source"] == "program_span":
             assert got[name]["value"] >= 0 and got[name]["unit"] == spec["unit"], name
         else:
             assert name not in got  # no TPU plane in a CPU trace
-    if workload == "kron19-rescale":
+    if workload in RESCALE:
         assert got["relayout_ms"]["value"] > 0
         assert got["rescale_compiles"]["value"] == 0  # set-up warmed both layouts
     else:
