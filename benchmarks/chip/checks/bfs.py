@@ -2,9 +2,16 @@
 
 ``bfs_wrong`` counts the vertices whose distance differs from
 ``reference.bfs`` over the live graph, in ``check_searches`` of the window's
-searches drawn from the seed (the mix's ``search``); its limit is 0. Every
-search is given the edge count and size of its root's component, which
-TEPS and the roofline read.
+searches drawn from the seed (the mix's ``search``); its limit is 0.
+
+Every mix's search check, whichever ``checks/<check>.py`` it is, keeps this
+contract with the readers: it gives every search operation
+``component_edges`` (the input edges of its root's component) and
+``reached`` (that component's vertices), and removes ``dist``. ``bfs_teps``
+and ``idle_share.bfs`` then read any such mix. ``bfs_search_roofline``
+counts a BFS's bytes (``reference.bfs_least_bytes``: 8 B an edge, 4 B a
+reached vertex); a search that reads more, such as a weight column, brings
+a roofline metric file of its own.
 """
 import numpy as np
 

@@ -29,19 +29,22 @@ def readings(root, workload, seconds, seeds, controls, *, require_tpu=True):
     ctx, cell = harness.prepare(root, workload, seeds[0], seconds, False,
                                 require_tpu=require_tpu)
     planted = None
-    for i, seed in enumerate(list(seeds) + list(controls)):
-        if i == len(seeds):
-            planted = faults.control_for(cell.mix)
-            planted(cell)
-        if i:
-            cell.reseed(seed)
-        t = time.perf_counter()
-        out = harness.finish(ctx, cell, harness.measure(ctx, cell, seconds, False))
-        yield {"seed": seed, "control": planted.__name__ if planted else None,
-               "correct": out["correct"], "attempted": out["attempted"],
-               "checks": {k: c["value"] for k, c in out["checks"].items()},
-               "metrics": {k: m["value"] for k, m in out["metrics"].items()},
-               "s": round(time.perf_counter() - t, 3)}
+    try:
+        for i, seed in enumerate(list(seeds) + list(controls)):
+            if i == len(seeds):
+                planted = faults.control_for(cell.mix)
+                planted(cell)
+            if i:
+                cell.reseed(seed)
+            t = time.perf_counter()
+            out = harness.finish(ctx, cell, harness.measure(ctx, cell, seconds, False))
+            yield {"seed": seed, "control": planted.__name__ if planted else None,
+                   "correct": out["correct"], "attempted": out["attempted"],
+                   "checks": {k: c["value"] for k, c in out["checks"].items()},
+                   "metrics": {k: m["value"] for k, m in out["metrics"].items()},
+                   "s": round(time.perf_counter() - t, 3)}
+    finally:
+        harness.close(ctx)
 
 
 def main() -> int:

@@ -91,11 +91,12 @@ def no_exchange(cell):
 
 
 def unchanged_search(cell):
-    """A search returns its initial state: only the root reached."""
+    """A search returns its initial state: only the root reached. Like each
+    search fault, it takes whatever operands the mix passes, root last."""
     import jax.numpy as jnp
 
-    def prog(edges, mask, root):
-        return jnp.full((cell.v,), 1e9).at[root].set(0.0), 1
+    def prog(*operands):
+        return jnp.full((cell.v,), 1e9).at[operands[-1]].set(0.0), 1
     cell.prog = prog
 
 
@@ -103,17 +104,19 @@ def altered_search(cell):
     """A search returns the root at distance 1."""
     real = cell.prog
 
-    def prog(edges, mask, root):
-        dist, iters = real(edges, mask, root)
-        return dist.at[root].set(1.0), iters
+    def prog(*operands):
+        dist, iters = real(*operands)
+        return dist.at[operands[-1]].set(1.0), iters
     cell.prog = prog
 
 
 def early_stop(cell):
-    """Searches stop after two levels, as too low a bound on iterations would."""
+    """Searches stop after two levels, as too low a bound on iterations
+    would: the mix's own program, built with ``max_iters=2``."""
     from repro.graphs import engine as GE
 
-    cell.prog = GE.query_program("sssp", num_vertices=cell.v, mesh=cell.mesh, max_iters=2)
+    cell.prog = GE.query_program(cell.mix["search"]["program"], num_vertices=cell.v,
+                                 mesh=cell.mesh, max_iters=2)
 
 
 def control_for(mix: dict):

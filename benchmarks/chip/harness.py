@@ -22,9 +22,14 @@ adding files:
 * ``ops/<kind>.py`` holds one kind of operation: ``setup(cell)``, run once
   before the set-up rounds; ``run(cell)``, one operation, returning what it
   did; and, where the kind draws traffic, ``reseed(cell)``, which draws it
-  anew from ``cell.seed``;
+  anew from ``cell.seed``. A search mix's ``search`` group may name the
+  ``operands`` its program reads: fields of the engine's live pack, passed
+  in order before the root (``["edges", "mask"]`` where it names none);
 * ``checks/<check>.py`` holds one comparison with a plain reference:
-  ``read(cell, run)`` returns ``{number: (value, limit)}``;
+  ``read(cell, run)`` returns ``{number: (value, limit)}``. A mix's search
+  check, whichever it is, gives every search operation ``component_edges``
+  and ``reached`` and removes ``dist`` (``checks/bfs.py``), so that the
+  search metrics read any search mix;
 * ``metrics/<metric>.py`` holds one metric's reader: ``read(run)`` returns
   the number, or None where the run has nothing to read.
 
@@ -33,6 +38,10 @@ The program under test is the repo's ``src/repro``: ``core.hier_order``
 preprocess, ``IncrementalOrderer``, ``StreamingEngine``,
 ``ElasticController`` and ``graphs.engine.query_program``; it sees only the
 generated inputs.
+
+The run's ``Tracer`` is the process-global one (``repro.obs.trace``) from
+set-up until the run ends, so that a component built without a tracer of its
+own records into ``run.spans`` too; it is disabled without ``--trace 1``.
 """
 from __future__ import annotations
 
@@ -329,12 +338,22 @@ class Context:
     peaks: dict
     tracer: object
     counter: CompileCounter
+    previous_tracer: object  # the process-global tracer before the run
+
+
+def close(ctx: Context) -> None:
+    """End the run: put back the process-global tracer it replaced."""
+    from repro.obs import trace as OT
+
+    OT.set_tracer(ctx.previous_tracer)
 
 
 def prepare(root: str, workload: str, seed: int, seconds: float, trace: bool, *,
             require_tpu: bool = True) -> tuple[Context, Cell]:
-    """Everything before the window: find the cell, check the chip, load the
-    graph and its order, commit, generate the traffic, warm up."""
+    """Everything before the window: find the cell, check the chip, install
+    the run's tracer as the process-global one, load the graph and its
+    order, commit, generate the traffic, warm up. The caller ends the run
+    with ``close``; where set-up fails, it is closed here."""
     import jax
 
     bench, spec, config, mix = find_cell(root, workload)
@@ -351,7 +370,22 @@ def prepare(root: str, workload: str, seed: int, seconds: float, trace: bool, *,
 
     ctx = Context(root, workload, specs, {m["name"]: reader(root, m["name"]) for m in specs},
                   devs[:chips], P.peaks(devs[0].device_kind) if require_tpu else None,
-                  OT.Tracer(capacity=1 << 20, enabled=trace, annotate=trace), CompileCounter())
+                  OT.Tracer(capacity=1 << 20, enabled=trace, annotate=trace), CompileCounter(),
+                  OT.get_tracer())
+    OT.set_tracer(ctx.tracer)
+    try:
+        return ctx, _set_up(ctx, bench, spec, config, mix, seed, seconds, chips)
+    except BaseException:
+        close(ctx)
+        raise
+
+
+def _set_up(ctx: Context, bench: dict, spec: dict, config: dict, mix: dict, seed: int,
+            seconds: float, chips: int) -> Cell:
+    """``prepare``'s work from the order load on; returns the warm cell."""
+    import jax
+
+    root = ctx.root
     conf_file = {c["name"]: c for c in bench["configs"]}[spec["config"]]["file"]
     t = time.perf_counter()
     base, ordered, cached = graph_and_order(root, spec["config"], config,
@@ -368,7 +402,7 @@ def prepare(root: str, workload: str, seed: int, seconds: float, trace: bool, *,
     cell.snapshots.clear()
     _say(f"setup warm_up {time.perf_counter() - t:.3f} s ops={len(warm)} "
          + " ".join(f"{o.kind}={o.t1 - o.t0:.3f}" for o in warm if o.kind != "batch"))
-    return ctx, cell
+    return cell
 
 
 def measure(ctx: Context, cell: Cell, seconds: float, trace: bool) -> Run:
@@ -445,13 +479,16 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool, *
     ``on_window(cell)`` runs between set-up and window (tests break the timed
     path there)."""
     ctx, cell = prepare(root, workload, seed, seconds, trace, require_tpu=require_tpu)
-    setup_s = time.perf_counter() - t_start
-    _say(f"setup total {setup_s:.3f} s")
-    if on_window is not None:
-        on_window(cell)
-    run = measure(ctx, cell, seconds, trace)
-    run.setup_s = setup_s
-    return finish(ctx, cell, run)
+    try:
+        setup_s = time.perf_counter() - t_start
+        _say(f"setup total {setup_s:.3f} s")
+        if on_window is not None:
+            on_window(cell)
+        run = measure(ctx, cell, seconds, trace)
+        run.setup_s = setup_s
+        return finish(ctx, cell, run)
+    finally:
+        close(ctx)
 
 
 def emit(out: dict) -> None:

@@ -458,6 +458,133 @@ def test_an_unknown_generator_is_refused(tmp_path):
         run(r, add_cell_on_config(r, conf))
 
 
+def add_search_cell(r, name: str, operands: list, per_layer=()) -> str:
+    """A one-chip cell ``name`` on ``gap-urand-s19`` whose mix is ``bfs.json``
+    with ``operands``, read by ``bfs_teps`` and ``idle_share.bfs`` and by the
+    ``per_layer`` metrics named; returns the cell's name."""
+    d = os.path.join(r, harness.BENCH_REL)
+    mix = harness.load_json(os.path.join(d, "traffic", "bfs.json"))
+    mix["search"]["operands"] = operands
+    with open(os.path.join(d, "traffic", name + ".json"), "w") as f:
+        json.dump(mix, f)
+    path = os.path.join(r, "BENCHMARK.json")
+    bench = harness.load_json(path)
+    bench["workloads"].append({"name": name, "config": "gap-urand-s19", "traffic": name,
+                               "chips": 1, "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in ("bfs_teps", "idle_share.bfs"):
+            m["workloads"].append(name)
+    bench["per_layer"] += [{"name": n, "unit": "count", "better": "higher",
+                            "source": "program_span", "layer": "test", "moves": "bfs_teps",
+                            "workloads": [name]} for n in per_layer]
+    with open(path, "w") as f:
+        json.dump(bench, f)
+    return name
+
+
+# Readers of a query program's own spans, as a later PR would add them.
+QUERY_SPANS = "def read(run):\n    return sum(s.name == 'query.sssp' for s in run.spans) or None\n"
+QUERY_ANNOTATIONS = ("def read(run):\n"
+                     "    return len(run.trace.annotations('query.sssp')) or None\n")
+
+
+@pytest.fixture(scope="module")
+def operand_root(tmp_path_factory):
+    """A tiny checkout with cell ``degrees-cell``: a search mix whose program
+    reads the pack's ``degrees`` after its edges and mask."""
+    r = tiny_root(tmp_path_factory.mktemp("operands"), quiet=True)
+    for name, text in (("query_spans", QUERY_SPANS), ("query_annotations", QUERY_ANNOTATIONS)):
+        with open(os.path.join(r, harness.BENCH_REL, "metrics", name + ".py"), "w") as f:
+            f.write(text)
+    add_search_cell(r, "degrees-cell", ["edges", "mask", "degrees"],
+                    per_layer=["query_spans", "query_annotations"])
+    return r
+
+
+@pytest.fixture
+def handed(monkeypatch):
+    """Every query program takes ``(edges, mask, degrees, root)``: it records
+    what it was handed, opens a ``query.sssp`` span on the process-global
+    tracer (as a program built with no tracer of its own does), and
+    searches with ``(edges, mask, root)``. Yields the list of calls."""
+    from repro.graphs import engine as GE
+    from repro.obs import trace as OT
+
+    calls, real = [], GE.query_program
+
+    def query_program(kind, **kw):
+        prog = real(kind, **kw)
+
+        def searched(edges, mask, degrees, root):
+            calls.append((edges, mask, degrees, root))
+            with OT.span("query.sssp"):
+                return prog(edges, mask, root)
+        return searched
+    monkeypatch.setattr(GE, "query_program", query_program)
+    return calls
+
+
+def test_a_search_mix_passes_the_pack_fields_it_names(operand_root, handed, capsys):
+    """A search whose program reads a third field of the pack is added as
+    data and metric files: the fields arrive in the mix's order before the
+    root, read from the live pack, and ``bfs_teps`` and ``idle_share.bfs``
+    read the cell."""
+    cells = []
+    line = last_line(run(operand_root, "degrees-cell", on_window=cells.append), capsys)
+    assert line["correct"] is True and line["checks"]["bfs_wrong"]["value"] == 0
+    assert line["metrics"]["bfs_teps"]["value"] > 0
+    data = cells[0].eng.data
+    window = handed[1:]  # the first search warmed the program in set-up
+    assert len(window) == line["attempted"] > 0
+    for edges, mask, degrees, root in window:
+        assert edges is data.edges and mask is data.mask and degrees is data.degrees
+        assert isinstance(root, int) and degrees.shape == (1 << TINY_SCALE,)
+    line = last_line(run(operand_root, "degrees-cell", trace=True), capsys)
+    assert line["correct"] is True and "idle_share.bfs" in line["metrics"]
+
+
+def test_a_traced_run_records_spans_of_a_component_without_a_tracer(operand_root, handed,
+                                                                    capsys):
+    """The run's tracer is the process-global one while the run lasts: a
+    program that records to ``get_tracer()`` reaches ``run.spans`` and, as a
+    ``query.`` annotation, the trace; an untraced run records nothing; the
+    tracer before the run is back after it."""
+    from repro.obs import trace as OT
+
+    before = OT.get_tracer()
+    line = last_line(run(operand_root, "degrees-cell", trace=True), capsys)
+    assert OT.get_tracer() is before
+    assert line["metrics"]["query_spans"]["value"] == line["attempted"] > 0
+    assert line["metrics"]["query_annotations"]["value"] == line["attempted"]
+    ctx, cell = harness.prepare(operand_root, "degrees-cell", 7, 0.3, False, require_tpu=False)
+    try:
+        assert OT.get_tracer() is ctx.tracer and not ctx.tracer.enabled
+        r = harness.measure(ctx, cell, 0.3, False)
+        assert r.spans == [] and len(r.of("search")) > 0
+    finally:
+        harness.close(ctx)
+    assert OT.get_tracer() is before
+
+
+def test_an_unknown_search_operand_is_refused_before_the_window(tmp_path, handed):
+    from repro.obs import trace as OT
+
+    r = tiny_root(tmp_path)
+    before, windows = OT.get_tracer(), []
+    with pytest.raises(harness.BenchError, match="weights"):
+        run(r, add_search_cell(r, "weights-cell", ["edges", "mask", "weights"]),
+            on_window=windows.append)
+    assert windows == [] and handed == [] and OT.get_tracer() is before
+
+
+@pytest.mark.parametrize("fault", [faults.unchanged_search, faults.altered_search,
+                                   faults.early_stop])
+def test_each_search_fault_takes_the_mix_operands(operand_root, handed, fault, capsys):
+    line = last_line(run(operand_root, "degrees-cell", on_window=fault), capsys)
+    assert line["correct"] is False and line["failed"] > 0
+    assert line["checks"]["bfs_wrong"]["value"] > 0
+
+
 @pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
 def test_generator_modules_give_graphgen_bytes_order_and_pack(root, config):
     """Each configuration's generator module returns what ``graphgen`` gives,

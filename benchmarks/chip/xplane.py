@@ -109,7 +109,8 @@ def _short(name: str) -> str:
     return name.split("(", 1)[0]
 
 
-def load(path: str, host_prefixes=("bench.", "ingest.", "rung.", "rescale.", "rebuild.")) -> Trace:
+def load(path: str, host_prefixes=("bench.", "ingest.", "rung.", "rescale.", "rebuild.",
+                                   "query.")) -> Trace:
     """Read one ``.xplane.pb``; host annotations are kept when their name
     starts with one of ``host_prefixes``."""
     from jax.profiler import ProfileData
