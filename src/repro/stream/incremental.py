@@ -45,7 +45,7 @@ from .updates import EdgeUpdateBatch
 
 __all__ = ["StreamConfig", "IncrementalOrderer", "SlotOp", "best_insert_position"]
 
-_NO_SLOTS: frozenset = frozenset()  # the incident set of a vertex with no edges
+_NO_IDS: frozenset = frozenset()  # the incident set of a vertex with no edges
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,12 +118,39 @@ def best_insert_position(
     return candidates[int(np.argmin(scores))]  # argmin keeps first on ties
 
 
+def _spread(e: int, regions: int, spr: int) -> tuple[np.ndarray, np.ndarray]:
+    """CEP chunks of ``e`` ordered edges over ``regions`` regions of ``spr``
+    slots, each chunk spread evenly over its region (PMA style): returns
+    (slot of each edge, counted from the first region's start; the chunk
+    bounds). Edge j of chunk p, n_p edges from bounds[p], lands on column
+    ``(j - bounds[p]) · spr // n_p`` — the layout
+    ``kernels/span_reorder.splice_targets_device`` writes on the mesh. One
+    vector pass per region: chunks are contiguous, so no per-edge region
+    lookup is needed."""
+    bounds = np.asarray(cep.chunk_bounds(e, regions), dtype=np.int64)
+    slots = np.empty(e, dtype=np.int64)
+    for r in range(regions):
+        lo, hi = int(bounds[r]), int(bounds[r + 1])
+        if hi > lo:
+            col = np.arange(hi - lo, dtype=np.int64)
+            col *= spr
+            col //= hi - lo
+            col += r * spr
+            slots[lo:hi] = col
+    return slots, bounds
+
+
 class IncrementalOrderer:
     """Maintains the ordered edge list in a region-sliced slot array.
 
     The slot array is the host source of truth the device streaming pack
     mirrors slot-for-slot (``ingest.StreamingEngine``); ``drain_ops`` hands
     the engine exactly the slots each ``apply`` touched.
+
+    Per-edge maps: ``_edge_ids`` gives each live edge (u, v) a stable id,
+    ``_incident[w]`` holds the ids of vertex w's edges, and two arrays place
+    the ids: ``_slot_of[id]`` → slot and ``_eid_at[slot]`` → id (-1 empty).
+    A re-layout that keeps the order moves only the two arrays.
 
     ``tracer`` is the ``obs.trace.Tracer`` its spans go to: the streaming
     engine hands over its own at construction; None means the process-global
@@ -183,7 +210,7 @@ class IncrementalOrderer:
 
     @property
     def num_edges(self) -> int:
-        return len(self._edge2slot)
+        return len(self._edge_ids)
 
     @property
     def delta(self) -> int:
@@ -193,88 +220,143 @@ class IncrementalOrderer:
 
     # ---------------------------------------------------------------- layout
     def _layout(self, src_o: np.ndarray, dst_o: np.ndarray, regions: int,
-                spr: Optional[int] = None, span: str = "layout") -> np.ndarray:
+                spr: Optional[int] = None, span: str = "layout",
+                eids: Optional[np.ndarray] = None) -> np.ndarray:
         """(Re)build the slot array from an ordered list: CEP chunk at
         k=regions, each chunk's edges spread evenly over its region's slots so
-        gaps are interleaved (PMA style) and early inserts never shift. Its
-        steps are spans ``<span>.fill``, ``.edge_map``, ``.region_counts``
-        and ``.incident``. Returns the new slot of each input edge (ascending,
-        since the spread keeps the input order)."""
+        gaps are interleaved (PMA style) and early inserts never shift.
+        Returns the new slot of each input edge (ascending, since the spread
+        keeps the input order).
+
+        ``eids``, the edge ids of the listed edges, says the order is KEPT
+        (relayout, grow): the per-edge maps survive and only the two slot
+        arrays move. Without it the order is new (init, full rebuild) and the
+        maps are built with ids 0..E-1. The span ``<span>`` counts
+        ``maps_moved`` or ``maps_built`` (edges) and holds ``.fill``,
+        ``.edge_map`` (edge → slot) ``.region_counts`` and ``.incident``
+        (slot → edge, and the incident sets when built)."""
         e = int(src_o.shape[0])
-        if spr is None:
-            raw = max(2, int(np.ceil(e * (1.0 + self.config.slack) / regions)))
-            prev = self._spr if getattr(self, "_regions", None) == regions else None
-            if prev is not None and prev >= raw:
-                # Same region count and the current width still fits: KEEP it.
-                # slots_per_region defines the device buffer width, i.e. the
-                # static signature of every cached scatter / compact /
-                # span-repair program — a full rebuild at |E|+500 must not
-                # recompile three programs.
-                spr = prev
-            else:
-                # Fresh width: 25% growth headroom, 256-aligned, so a k-phase
-                # of steady ingest re-laying out at every full rebuild stays
-                # on one program signature (compiles only at k changes, which
-                # the engine warms inside the rescale).
-                spr = max(2, -(-int(np.ceil(raw * 1.25)) // 256) * 256)
-        self._regions = int(regions)
-        self._spr = int(spr)
-        # Checkpoint bookkeeping (DESIGN.md §15): a re-layout rewrites every
-        # region, invalidates slot-addressed recovery ops, and changes the
-        # chunk geometry the incremental snapshot addresses by — bump the
-        # epoch so the checkpoint layer forces a full snapshot.
-        self.layout_epoch = getattr(self, "layout_epoch", -1) + 1
-        self._dirty_regions: set[int] = set(range(int(regions)))
-        self._rec_ops: dict[int, tuple[int, int, bool]] = {}
-        c = self.capacity
-        self.slot_src = np.zeros(c, dtype=np.int64)
-        self.slot_dst = np.zeros(c, dtype=np.int64)
-        self.slot_valid = np.zeros(c, dtype=bool)
-        self._edge2slot: dict[tuple[int, int], int] = {}
-        self._incident: dict[int, set] = {}
-        self._rc: list[dict[int, int]] = [dict() for _ in range(regions)]
-        self._free = np.full(regions, self._spr, dtype=np.int64)  # free slots/region
-        # Per-region sorted free-slot arrays, built lazily (one vectorized scan
-        # per region per batch) and maintained incrementally as slots fill /
-        # free — the batched replacement for the per-insert occupancy rescans
-        # the placement loop used to do (ROADMAP follow-up).
-        self._free_cache: list = [None] * int(regions)
-        self._gather_from = None  # new slot ← old slot; only relayout builds it
-        if e == 0:
-            return np.zeros(0, dtype=np.int64)
-        # Vectorized fill (the same CEP spread the device splice computes):
-        # the per-edge dict/set bookkeeping below is bulk-built — this runs on
-        # every full rebuild and relayout, so it must not out-cost geo_order.
         tr = self._spans
-        with tr.span(span + ".fill"):
-            bounds = np.asarray(cep.chunk_bounds(e, regions), dtype=np.int64)
-            sizes = np.diff(bounds)
-            if int(sizes.max()) > self._spr:
-                p_bad = int(np.argmax(sizes))
-                raise ValueError(
-                    f"region {p_bad} chunk ({int(sizes[p_bad])} edges) exceeds "
-                    f"slots_per_region={self._spr}"
-                )
-            j = np.arange(e, dtype=np.int64)
-            p = np.asarray(cep.id2p(e, regions, j), dtype=np.int64)
-            n_p = bounds[p + 1] - bounds[p]
-            cols = ((j - bounds[p]) * self._spr) // n_p
-            slots = p * self._spr + cols
-            self.slot_src[slots] = src_o
-            self.slot_dst[slots] = dst_o
-            self.slot_valid[slots] = True
-            self._free -= np.bincount(p, minlength=regions)
-        with tr.span(span + ".edge_map"):
-            self._edge2slot = dict(zip(zip(src_o.tolist(), dst_o.tolist()), slots.tolist()))
-        with tr.span(span + ".region_counts"):
-            self._rebuild_region_counts(0, regions, p, src_o, dst_o)
-        with tr.span(span + ".incident"):
-            idx, ws, starts, ends = self._vertex_groups(np.concatenate([src_o, dst_o]))
-            sslots = np.concatenate([slots, slots])[idx].tolist()
-            self._incident = {
-                w: set(sslots[a:b]) for w, a, b in zip(ws, starts, ends)
-            }
+        with tr.span(span) as lsp:
+            lsp.count(**{"maps_moved" if eids is not None else "maps_built": e})
+            if spr is None:
+                raw = max(2, int(np.ceil(e * (1.0 + self.config.slack) / regions)))
+                prev = self._spr if getattr(self, "_regions", None) == regions else None
+                if prev is not None and prev >= raw:
+                    # Same region count and the current width still fits: KEEP it.
+                    # slots_per_region defines the device buffer width, i.e. the
+                    # static signature of every cached scatter / compact /
+                    # span-repair program — a full rebuild at |E|+500 must not
+                    # recompile three programs.
+                    spr = prev
+                else:
+                    # Fresh width: 25% growth headroom, 256-aligned, so a k-phase
+                    # of steady ingest re-laying out at every full rebuild stays
+                    # on one program signature (compiles only at k changes, which
+                    # the engine warms inside the rescale).
+                    spr = max(2, -(-int(np.ceil(raw * 1.25)) // 256) * 256)
+            self._regions = int(regions)
+            self._spr = int(spr)
+            # Checkpoint bookkeeping (DESIGN.md §15): a re-layout rewrites every
+            # region, invalidates slot-addressed recovery ops, and changes the
+            # chunk geometry the incremental snapshot addresses by — bump the
+            # epoch so the checkpoint layer forces a full snapshot.
+            self.layout_epoch = getattr(self, "layout_epoch", -1) + 1
+            self._dirty_regions: set[int] = set(range(int(regions)))
+            self._rec_ops: dict[int, tuple[int, int, bool]] = {}
+            c = self.capacity
+            self.slot_src = np.zeros(c, dtype=np.int64)
+            self.slot_dst = np.zeros(c, dtype=np.int64)
+            self.slot_valid = np.zeros(c, dtype=bool)
+            self._eid_at = np.full(c, -1, dtype=np.int64)
+            self._rc: list[dict[int, int]] = [dict() for _ in range(regions)]
+            self._free = np.full(regions, self._spr, dtype=np.int64)  # free slots/region
+            # Per-region sorted free-slot arrays, built lazily (one vectorized scan
+            # per region per batch) and maintained incrementally as slots fill /
+            # free — the batched replacement for the per-insert occupancy rescans
+            # the placement loop used to do (ROADMAP follow-up).
+            self._free_cache: list = [None] * int(regions)
+            self._gather_from = None  # new slot ← old slot; only relayout builds it
+            # Vectorized fill (the same CEP spread the device splice computes):
+            # this runs on every full rebuild and relayout, so it must not
+            # out-cost geo_order.
+            with tr.span(span + ".fill"):
+                slots, bounds = _spread(e, regions, self._spr)
+                sizes = np.diff(bounds)
+                if int(sizes.max()) > self._spr:
+                    p_bad = int(np.argmax(sizes))
+                    raise ValueError(
+                        f"region {p_bad} chunk ({int(sizes[p_bad])} edges) exceeds "
+                        f"slots_per_region={self._spr}"
+                    )
+                self.slot_src[slots] = src_o
+                self.slot_dst[slots] = dst_o
+                self.slot_valid[slots] = True
+                self._free -= sizes
+            with tr.span(span + ".edge_map"):
+                if eids is None:
+                    self._map_edges(src_o, dst_o, slots)
+                else:
+                    self._slot_of[eids] = slots
+            with tr.span(span + ".region_counts"):
+                self._rebuild_region_counts(0, bounds, src_o, dst_o)
+            with tr.span(span + ".incident"):
+                if eids is None:
+                    self._map_incident(src_o, dst_o)
+                else:
+                    self._eid_at[slots] = eids
         return slots
+
+    def _map_edges(self, src_o: np.ndarray, dst_o: np.ndarray, slots: np.ndarray) -> None:
+        """Per-edge maps of a NEW order (``_eid_at`` already sized): the
+        listed edges, at ``slots``, get the ids 0..E-1."""
+        e = int(slots.size)
+        ids = np.arange(e, dtype=np.int64)
+        self._edge_ids = dict(zip(zip(src_o.tolist(), dst_o.tolist()), ids.tolist()))
+        self._slot_of = slots.astype(np.int64, copy=True)
+        self._eid_at[slots] = ids
+        self._n_eids = e  # ids handed out so far
+        self._free_eids: list[int] = []  # ids deletes freed, reused last-in first
+
+    def _map_incident(self, src_o: np.ndarray, dst_o: np.ndarray) -> None:
+        """Incident id sets of a NEW order: edge j (id j) joins the sets of
+        both its endpoints."""
+        e = int(src_o.shape[0])
+        if e == 0:
+            self._incident = {}
+            return
+        verts = np.concatenate([src_o, dst_o])
+        idx = np.argsort(verts, kind="stable")  # incidences grouped by vertex
+        sv = verts[idx]
+        cut = (np.flatnonzero(np.diff(sv)) + 1).tolist()
+        starts, ends = [0] + cut, cut + [sv.size]
+        sids = (idx % e).tolist()  # incidence i is edge i mod E
+        self._incident = {
+            w: set(sids[a:b]) for w, a, b in zip(sv[starts].tolist(), starts, ends)
+        }
+
+    def _new_eid(self) -> int:
+        """An id for an inserted edge: the last one a delete freed, else the
+        next unused (``_slot_of`` doubles when full)."""
+        if self._free_eids:
+            return self._free_eids.pop()
+        eid = self._n_eids
+        if eid == self._slot_of.size:
+            self._slot_of = np.concatenate(
+                [self._slot_of, np.full(max(eid, 64), -1, dtype=np.int64)]
+            )
+        self._n_eids = eid + 1
+        return eid
+
+    def slot_of(self, u: int, v: int) -> Optional[int]:
+        """Slot of the live edge (u, v) (canonical u < v), None if absent."""
+        eid = self._edge_ids.get((int(u), int(v)))
+        return None if eid is None else int(self._slot_of[eid])
+
+    def incident_slots(self, w: int) -> np.ndarray:
+        """Sorted slots of vertex ``w``'s live edges."""
+        ids = self._incident.get(int(w), _NO_IDS)
+        return np.sort(self._slot_of[np.fromiter(ids, dtype=np.int64, count=len(ids))])
 
     def _set_baseline(self) -> None:
         """Record the current normalized objective as 'fresh-GEO quality'.
@@ -291,34 +373,18 @@ class IncrementalOrderer:
         return self.region_vertex_sum() / max(1, self.num_vertices + self.num_edges + self._regions)
 
     # -------------------------------------------------------------- counters
-    @staticmethod
-    def _vertex_groups(verts: np.ndarray):
-        """Group a per-incidence vertex array: returns (idx, vertices, starts,
-        ends) where ``idx`` sorts the incidences by vertex and group g of the
-        sorted payload is ``[starts[g]:ends[g]]`` for ``vertices[g]`` — the
-        shared bulk-build step of ``_layout`` and ``_rewrite_span``'s
-        incident-set bookkeeping."""
-        if verts.size == 0:
-            return np.zeros(0, dtype=np.int64), [], [], []
-        idx = np.argsort(verts, kind="stable")
-        sv = verts[idx]
-        cut = np.flatnonzero(np.diff(sv)) + 1
-        starts = np.concatenate([[0], cut])
-        ends = np.concatenate([cut, [sv.size]])
-        return idx, sv[starts].tolist(), starts.tolist(), ends.tolist()
-
     def _rebuild_region_counts(
-        self, base: int, regions: int, p: np.ndarray, src_o: np.ndarray, dst_o: np.ndarray
+        self, base: int, bounds: np.ndarray, src_o: np.ndarray, dst_o: np.ndarray
     ) -> None:
-        """Region vertex counters for regions [base, base+regions) rebuilt
-        from their chunk assignment ``p`` — a region's counts are fully
-        determined by its chunk's endpoints."""
-        for ridx in range(regions):
-            sel = p == ridx
-            ids, cnt = np.unique(
-                np.concatenate([src_o[sel], dst_o[sel]]), return_counts=True
-            )
-            self._rc[base + ridx] = dict(zip(ids.tolist(), cnt.tolist()))
+        """Region vertex counters for regions [base, base + len(bounds) - 1)
+        rebuilt from their chunks ``[bounds[r], bounds[r+1])`` of the listed
+        edges — a region's counts are fully determined by its chunk's
+        endpoints."""
+        for ridx in range(len(bounds) - 1):
+            lo, hi = int(bounds[ridx]), int(bounds[ridx + 1])
+            cnt = np.bincount(np.concatenate([src_o[lo:hi], dst_o[lo:hi]]))
+            ids = np.flatnonzero(cnt)  # vertex ids ascending, as np.unique gives them
+            self._rc[base + ridx] = dict(zip(ids.tolist(), cnt[ids].tolist()))
 
     def _count(self, region: int, vertex: int, d: int) -> None:
         rc = self._rc[region]
@@ -385,9 +451,12 @@ class IncrementalOrderer:
                 "append_fallbacks": self._fallbacks}
 
     def _delete(self, u: int, v: int) -> bool:
-        s = self._edge2slot.pop((u, v), None)
-        if s is None:
+        eid = self._edge_ids.pop((u, v), None)
+        if eid is None:
             return False
+        self._free_eids.append(eid)
+        s = int(self._slot_of[eid])
+        self._eid_at[s] = -1
         region = s // self._spr
         self.slot_valid[s] = False
         self.slot_src[s] = 0
@@ -397,7 +466,7 @@ class IncrementalOrderer:
         for w in (u, v):
             inc = self._incident.get(w)
             if inc is not None:
-                inc.discard(s)
+                inc.discard(eid)
                 if not inc:
                     del self._incident[w]
             self._count(region, w, -1)
@@ -411,7 +480,7 @@ class IncrementalOrderer:
         if u == v:
             return None
         u, v = (u, v) if u < v else (v, u)
-        if (u, v) in self._edge2slot:
+        if (u, v) in self._edge_ids:
             return None
         if u < 0 or v >= self.num_vertices:
             # Negative ids would silently wrap in both host np.add.at and the
@@ -430,9 +499,12 @@ class IncrementalOrderer:
         self.slot_valid[slot] = True
         self._free[region] -= 1
         self._cache_fill(slot)
-        self._edge2slot[(u, v)] = slot
-        self._incident.setdefault(u, set()).add(slot)
-        self._incident.setdefault(v, set()).add(slot)
+        eid = self._new_eid()
+        self._edge_ids[(u, v)] = eid
+        self._slot_of[eid] = slot
+        self._eid_at[slot] = eid
+        self._incident.setdefault(u, set()).add(eid)
+        self._incident.setdefault(v, set()).add(eid)
         self._count(region, u, +1)
         self._count(region, v, +1)
         self._deg_delta[u] = self._deg_delta.get(u, 0) + 1
@@ -444,14 +516,16 @@ class IncrementalOrderer:
 
     def _median_slot(self, u: int, v: int) -> Optional[int]:
         """Median incident slot of (u, v) via an O(d) numpy partial sort — the
-        element at sorted index d // 2, exactly what sorting would pick."""
-        a = self._incident.get(u, _NO_SLOTS)
-        b = self._incident.get(v, _NO_SLOTS)
+        element at sorted index d // 2, exactly what sorting would pick. Ids
+        and slots of live edges correspond one to one, so the union of id
+        sets translates to the union of slot sets."""
+        a = self._incident.get(u, _NO_IDS)
+        b = self._incident.get(v, _NO_IDS)
         self._incident_entries += len(a) + len(b)
         union = a | b
         if not union:
             return None
-        arr = np.fromiter(union, dtype=np.int64, count=len(union))
+        arr = self._slot_of[np.fromiter(union, dtype=np.int64, count=len(union))]
         mid = arr.size // 2
         return int(np.partition(arr, mid)[mid])
 
@@ -589,7 +663,7 @@ class IncrementalOrderer:
         """Reconstruct an orderer from a raw slot triple, preserving gaps and
         tombstone positions EXACTLY (``__init__`` would re-spread the edges
         and lose the layout). This is the checkpoint-restore path: all derived
-        bookkeeping (edge→slot map, incident sets, region counters, free
+        bookkeeping (per-edge maps, incident sets, region counters, free
         lists) is rebuilt from the arrays, and ``baseline_kappa`` /
         ``cooldown`` re-inject the monitor control state so post-restore
         escalation decisions replay identically to the pre-failure timeline."""
@@ -624,19 +698,19 @@ class IncrementalOrderer:
         occ = np.flatnonzero(slot_valid)
         src_o = slot_src[occ]
         dst_o = slot_dst[occ]
-        o._edge2slot = dict(zip(zip(src_o.tolist(), dst_o.tolist()), occ.tolist()))
-        if len(o._edge2slot) != occ.size:
+        o._eid_at = np.full(slot_src.size, -1, dtype=np.int64)
+        o._map_edges(src_o, dst_o, occ)
+        if len(o._edge_ids) != occ.size:
             raise ValueError("slot arrays hold duplicate edges")
-        p = occ // o._spr
+        # occ ascends, so each region's edges are one run of it
+        bounds = np.searchsorted(occ, np.arange(regions + 1, dtype=np.int64) * o._spr)
         o._rc = [dict() for _ in range(regions)]
-        o._rebuild_region_counts(0, regions, p, src_o, dst_o)
+        o._rebuild_region_counts(0, bounds, src_o, dst_o)
         o._free = np.full(regions, o._spr, dtype=np.int64)
-        o._free -= np.bincount(p, minlength=regions)
+        o._free -= np.diff(bounds)
         o._free_cache = [None] * regions
         o._gather_from = None
-        idx, ws, starts, ends = cls._vertex_groups(np.concatenate([src_o, dst_o]))
-        sslots = np.concatenate([occ, occ])[idx].tolist()
-        o._incident = {w: set(sslots[a:b]) for w, a, b in zip(ws, starts, ends)}
+        o._map_incident(src_o, dst_o)
         if baseline_kappa is None:
             o._set_baseline()
         else:
@@ -657,7 +731,7 @@ class IncrementalOrderer:
     def snapshot(self) -> tuple[np.ndarray, np.ndarray]:
         """The flat ordered (src, dst) lists — occupied slots in slot order."""
         vs = self.slot_valid
-        return self.slot_src[vs].copy(), self.slot_dst[vs].copy()
+        return self.slot_src[vs], self.slot_dst[vs]  # a boolean index copies
 
     def graph(self) -> Graph:
         src, dst = self.snapshot()
@@ -879,11 +953,10 @@ class IncrementalOrderer:
     def _rewrite_span(self, r0: int, r1: int, src_o: np.ndarray, dst_o: np.ndarray) -> None:
         """Rewrite regions [r0, r1) with the span order (CEP chunks spread
         evenly). Bookkeeping is vectorized on the partial-rung hot path: a
-        re-order rewrites the SAME edge multiset, so ``_edge2slot`` needs only
-        value updates (one C-level dict.update), region counters rebuild from
-        per-chunk ``np.unique``, and incident sets swap old↔new slots in
-        per-vertex bulk ops — this host pass rides along every device span
-        repair, so it must not cost what the repair saves."""
+        re-order rewrites the SAME edge multiset, so each edge keeps its id
+        and only ``_slot_of`` / ``_eid_at`` move, and region counters rebuild
+        from per-chunk ``np.bincount`` — this host pass rides along every
+        device span repair, so it must not cost what the repair saves."""
         spr = self._spr
         lo, hi = r0 * spr, r1 * spr
         src_o = np.asarray(src_o, dtype=np.int64)
@@ -893,69 +966,55 @@ class IncrementalOrderer:
         old_slots = lo + old_rel
         old_u = self.slot_src[old_slots].copy()
         old_v = self.slot_dst[old_slots].copy()
+        old_eids = self._eid_at[old_slots]
         same_edges = e == old_slots.size and np.array_equal(
             np.sort(old_u * self.num_vertices + old_v),
             np.sort(src_o * self.num_vertices + dst_o),
         )
         if not same_edges:
             # General path (never hit by re-orders): old edges leave the maps.
-            for s_, a, b in zip(old_slots.tolist(), old_u.tolist(), old_v.tolist()):
-                del self._edge2slot[(a, b)]
+            for eid, a, b in zip(old_eids.tolist(), old_u.tolist(), old_v.tolist()):
+                del self._edge_ids[(a, b)]
+                self._free_eids.append(eid)
                 for w in (a, b):
                     inc = self._incident.get(w)
                     if inc is not None:
-                        inc.discard(s_)
+                        inc.discard(eid)
                         if not inc:
                             del self._incident[w]
         self.slot_valid[lo:hi] = False
         self.slot_src[lo:hi] = 0
         self.slot_dst[lo:hi] = 0
+        self._eid_at[lo:hi] = -1
         self._free[r0:r1] = spr
         for r in range(r0, r1):  # bulk rewrite: rescan these regions lazily
             self._free_cache[r] = None
-        # Re-fill: CEP chunks of the span order over the span regions, slot
-        # targets computed in one closed-form vector pass (the exact layout
-        # kernels/span_reorder.splice_targets_device writes on the mesh).
-        regions = r1 - r0
+        # Re-fill: CEP chunks of the span order over the span regions.
+        slots, bounds = _spread(e, r1 - r0, spr)
         if e:
-            j = np.arange(e, dtype=np.int64)
-            p = np.asarray(cep.id2p(e, regions, j), dtype=np.int64)
-            bounds = np.asarray(cep.chunk_bounds(e, regions), dtype=np.int64)
-            n_p = bounds[p + 1] - bounds[p]
-            cols = ((j - bounds[p]) * spr) // n_p
-            slots = (r0 + p) * spr + cols
+            slots += lo
             self.slot_src[slots] = src_o
             self.slot_dst[slots] = dst_o
             self.slot_valid[slots] = True
-            self._free[r0:r1] -= np.bincount(p, minlength=regions)
-            self._edge2slot.update(
-                zip(zip(src_o.tolist(), dst_o.tolist()), slots.tolist())
-            )
-        else:
-            p = np.zeros(0, dtype=np.int64)
-            slots = np.zeros(0, dtype=np.int64)
-        self._rebuild_region_counts(r0, regions, p, src_o, dst_o)
-        # Incident sets: swap each affected vertex's old span slots for its
-        # new ones in one difference/update pair per vertex.
-        if same_edges:
-            # Align old and new slots per EDGE: both keyed by (u, v); the
-            # edge multiset is identical, so sorting by edge key pairs them.
-            old_key = np.argsort(old_u * self.num_vertices + old_v, kind="stable")
-            new_key = np.argsort(src_o * self.num_vertices + dst_o, kind="stable")
-            edge_new_slot = np.empty(e, dtype=np.int64)
-            edge_new_slot[old_key] = slots[new_key]
-            idx, ws, starts, ends = self._vertex_groups(np.concatenate([old_u, old_v]))
-            # python-list slicing beats np.split's per-group view construction
-            olds_l = np.concatenate([old_slots, old_slots])[idx].tolist()
-            news_l = np.concatenate([edge_new_slot, edge_new_slot])[idx].tolist()
-            for w, g0, g1 in zip(ws, starts, ends):
-                inc = self._incident[w]
-                inc.difference_update(olds_l[g0:g1])
-                inc.update(news_l[g0:g1])
-        else:
-            for s_, a, b in zip(slots.tolist(), src_o.tolist(), dst_o.tolist()):
-                self._incident.setdefault(a, set()).add(s_)
-                self._incident.setdefault(b, set()).add(s_)
+            self._free[r0:r1] -= np.diff(bounds)
+            if same_edges:
+                # Pair old and new positions per EDGE: the edge multiset is
+                # identical, so sorting both by edge key aligns them.
+                old_key = np.argsort(old_u * self.num_vertices + old_v, kind="stable")
+                new_key = np.argsort(src_o * self.num_vertices + dst_o, kind="stable")
+                new_eids = np.empty(e, dtype=np.int64)
+                new_eids[new_key] = old_eids[old_key]
+                self._slot_of[new_eids] = slots
+                self._eid_at[slots] = new_eids
+            else:
+                for s_, a, b in zip(slots.tolist(), src_o.tolist(), dst_o.tolist()):
+                    eid = self._new_eid()
+                    self._edge_ids[(a, b)] = eid
+                    self._slot_of[eid] = s_
+                    self._eid_at[s_] = eid
+                    self._incident.setdefault(a, set()).add(eid)
+                    self._incident.setdefault(b, set()).add(eid)
+        self._rebuild_region_counts(r0, bounds, src_o, dst_o)
         self._dirty_regions.update(range(r0, r1))
         # Recovery ops: the span rewrite touched every slot of [lo, hi), and
         # the device rung's emit_ops=False path bypasses ``_ops`` entirely —
@@ -1052,8 +1111,9 @@ class IncrementalOrderer:
         """Re-slice the CURRENT incremental order into ``regions`` regions
         (rescale k→k' under ingest: order unchanged, slots re-chunked). Sets
         ``needs_resync``; ``drain_gather_map`` feeds the on-device compact.
-        The span ``rescale.relayout`` (counts ``edges``, ``slots``) holds one
-        child per step."""
+        The per-edge maps move with the slots; none is rebuilt. The span
+        ``rescale.relayout`` (counts ``edges``, ``slots``) holds one child per
+        step."""
         tr = self._spans
         with tr.span("rescale.relayout") as sp:
             d = self.drift()  # Σ|V_p| scales with the region count, so carry the
@@ -1062,8 +1122,9 @@ class IncrementalOrderer:
                 # The snapshot lists edges in ascending old-slot order, so
                 # edge j came from old_occupied[j].
                 old_occupied = np.flatnonzero(self.slot_valid)
-            with tr.span("rescale.relayout.layout"):
-                slots = self._layout(src_o, dst_o, int(regions), span="rescale.relayout.layout")
+                eids = self._eid_at[old_occupied]
+            slots = self._layout(src_o, dst_o, int(regions),
+                                 span="rescale.relayout.layout", eids=eids)
             with tr.span("rescale.relayout.gather_map") as gsp:
                 gm = np.full(self.capacity, -1, dtype=np.int64)
                 gm[slots] = old_occupied
@@ -1079,8 +1140,9 @@ class IncrementalOrderer:
         self._grows += 1
         d = self.drift()
         src_o, dst_o = self.snapshot()
+        eids = self._eid_at[self.slot_valid]
         spr = max(self._spr + 1, int(np.ceil(self._spr * factor)))
-        self._layout(src_o, dst_o, self._regions, spr=spr)
+        self._layout(src_o, dst_o, self._regions, spr=spr, eids=eids)
         self._finish_relayout()
         self._baseline_kappa = self._kappa() / max(d, 1e-12)
 

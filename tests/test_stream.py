@@ -18,6 +18,7 @@ from repro.stream import (
     SyntheticStream,
     best_insert_position,
 )
+from repro.stream.incremental import _spread
 
 given, settings, st = hypothesis_or_stub()
 
@@ -452,6 +453,7 @@ def test_splice_targets_device_match_host_layout_past_int32_products(n, span, sp
     want = p * spr + ((j - bounds[p]) * spr) // (bounds[p + 1] - bounds[p])
     np.testing.assert_array_equal(got[:n], want)
     assert np.all(got[n:] == cap)
+    np.testing.assert_array_equal(_spread(n, span, spr)[0], want)  # the host's own
 
 
 def _force_partial_engine(mode, seed=7, span_regions=2):
@@ -617,7 +619,7 @@ def test_relayout_gather_map_matches_edge_dict_reference(ordered, prep, k_new):
 
     o = _prepared_orderer(ordered, prep)
     old_src, old_dst = o.slot_src.copy(), o.slot_dst.copy()
-    old_slot = {e: int(s) for e, s in o._edge2slot.items()}
+    old_slot = {(a, b): o.slot_of(a, b) for a, b in zip(*(x.tolist() for x in o.snapshot()))}
     o.tracer = OT.Tracer(capacity=256)
     o.relayout(k_new)
     gm = o.drain_gather_map()
@@ -634,6 +636,47 @@ def test_relayout_gather_map_matches_edge_dict_reference(ordered, prep, k_new):
     by = {s.name: s for s in o.tracer.spans()}
     assert by["rescale.relayout.gather_map"].counts == {"pairs": o.num_edges}
     assert by["rescale.relayout"].counts["edges"] == o.num_edges
+
+
+def _maps_match_slot_arrays(o):
+    """Every live edge's slot and every vertex's incident slots, read
+    through the orderer's maps, equal a rebuild from the slot arrays."""
+    occ = np.flatnonzero(o.slot_valid)
+    src, dst = o.slot_src[occ].tolist(), o.slot_dst[occ].tolist()
+    assert o.num_edges == occ.size
+    assert {(a, b): o.slot_of(a, b) for a, b in zip(src, dst)} == dict(
+        zip(zip(src, dst), occ.tolist()))
+    incident: dict = {}
+    for s_, a, b in zip(occ.tolist(), src, dst):
+        incident.setdefault(a, set()).add(s_)
+        incident.setdefault(b, set()).add(s_)
+    assert set(o._incident) == set(incident)  # no vertex left with an empty set
+    for w in range(o.num_vertices):
+        assert set(o.incident_slots(w).tolist()) == incident.get(w, set())
+
+
+@pytest.mark.parametrize("k_new", [6, 3, 1])
+@pytest.mark.parametrize("prep", ["holes", "grow", "span_rewrite", "from_slots"])
+def test_relayout_moves_edge_maps_to_the_new_slots(ordered, prep, k_new):
+    """A relayout (k → k_new, then back to 4) keeps the per-edge maps and
+    moves only their slots: after each, every live edge's slot and every
+    vertex's incident slots equal a rebuild from the slot arrays, the maps
+    are the same objects, and the layout span counts ``maps_moved`` for
+    every edge."""
+    from repro.obs import trace as OT
+
+    o = _prepared_orderer(ordered, prep)
+    _maps_match_slot_arrays(o)
+    edge_ids, incident = o._edge_ids, o._incident
+    for k in (k_new, 4):
+        o.tracer = OT.Tracer(capacity=256)
+        o.relayout(k)
+        assert o.regions == k
+        _maps_match_slot_arrays(o)
+        assert o._edge_ids is edge_ids and o._incident is incident
+        by = {s.name: s for s in o.tracer.spans()}
+        assert by["rescale.relayout.layout"].counts == {"maps_moved": o.num_edges}
+        assert by["rescale.relayout"].counts["edges"] == o.num_edges
 
 
 def test_per_rung_counters_and_timings_recorded_on_ingest_events(ordered):
@@ -824,8 +867,24 @@ class _ReferencePlacementOrderer(IncrementalOrderer):
     decisions must be bit-identical, only faster)."""
 
     def _median_slot(self, u, v):
-        inc = sorted(self._incident.get(u, set()) | self._incident.get(v, set()))
-        return inc[len(inc) // 2] if inc else None
+        inc = np.union1d(self.incident_slots(u), self.incident_slots(v))
+        return int(inc[inc.size // 2]) if inc.size else None
+
+    def _rebuild_maps(self):
+        """Per-edge maps built anew from the slot arrays, as every relayout
+        did before the maps moved with their slots."""
+        occ = np.flatnonzero(self.slot_valid)
+        src_o, dst_o = self.slot_src[occ], self.slot_dst[occ]
+        self._map_edges(src_o, dst_o, occ)
+        self._map_incident(src_o, dst_o)
+
+    def relayout(self, regions):
+        super().relayout(regions)
+        self._rebuild_maps()
+
+    def grow(self, factor=2.0):
+        super().grow(factor)
+        self._rebuild_maps()
 
     def _free_in(self, region, near=None):
         lo = region * self._spr
@@ -845,11 +904,18 @@ class _ReferencePlacementOrderer(IncrementalOrderer):
         return int(free[np.argmin(np.abs(free - near))])
 
 
-@pytest.mark.parametrize("seed,delete_frac", [(2, 0.25), (5, 0.4), (9, 0.0)])
-def test_vectorized_placement_decisions_unchanged(seed, delete_frac):
-    """Stream identical batches (incl. grows and partial re-orders) through
-    the vectorized orderer and the reference implementation: every slot
-    assignment must be identical — the vectorization may only change speed."""
+@pytest.mark.parametrize("seed,delete_frac,relayouts", [
+    pytest.param(2, 0.25, {}, id="2-0.25"),
+    pytest.param(5, 0.4, {}, id="5-0.4"),
+    pytest.param(9, 0.0, {}, id="9-0.0"),
+    pytest.param(4, 0.25, {3: 6, 7: 4}, id="4-0.25-relayout-6-4"),
+])
+def test_vectorized_placement_decisions_unchanged(seed, delete_frac, relayouts):
+    """Stream identical batches (incl. grows, partial re-orders and, where
+    ``relayouts`` maps a batch to a region count, relayouts before it)
+    through the vectorized orderer and the reference implementation, whose
+    maps are rebuilt at every relayout: every slot assignment must be
+    identical — the vectorization may only change speed."""
     g = rmat_graph(7, 6, seed=0)
     order = ordering.geo_order(g, seed=0)
     src, dst = g.src[order].astype(np.int64), g.dst[order].astype(np.int64)
@@ -859,6 +925,9 @@ def test_vectorized_placement_decisions_unchanged(seed, delete_frac):
     s2 = SyntheticStream(g, batch_size=64, delete_frac=delete_frac, seed=seed)
     decisions = ("inserted", "deleted", "skipped", "grows", "append_fallbacks")
     for i in range(10):
+        if i in relayouts:
+            fast.relayout(relayouts[i])
+            ref.relayout(relayouts[i])
         c1 = fast.apply(s1.batch())
         c2 = ref.apply(s2.batch())
         # The work counts (incident_entries, free_entries) are the two
@@ -871,6 +940,7 @@ def test_vectorized_placement_decisions_unchanged(seed, delete_frac):
         np.testing.assert_array_equal(fast.slot_dst, ref.slot_dst)
         np.testing.assert_array_equal(fast.slot_valid, ref.slot_valid)
     assert fast.slots_per_region == ref.slots_per_region
+    assert fast.regions == ref.regions == 4
 
 
 def test_free_slot_cache_stays_exact(ordered):
