@@ -15,7 +15,7 @@ from .common import bench_graph, emit
 
 def run(scale: int = 11, edge_factor: int = 10, k: int = 8) -> None:
     g = bench_graph(scale, edge_factor)
-    mesh = MM.make_test_mesh(1, 1)
+    mesh = MM.make_graph_mesh(1)
     geo = ordering.geo_order(g, seed=0)
     partitions = {
         "geo+cep": None,  # via cep_engine_data
@@ -26,14 +26,15 @@ def run(scale: int = 11, edge_factor: int = 10, k: int = 8) -> None:
     for name, part in partitions.items():
         data = E.cep_engine_data(g, geo, k) if part is None else E.build_engine_data(g, part, k)
         com = E.comm_volume_per_iteration(data)
+        sdata = E.shard_engine_data(data, mesh)
         t0 = time.perf_counter()
-        pr = E.pagerank(data, mesh, iterations=10)
+        pr = E.pagerank(sdata, iterations=10)
         t_pr = (time.perf_counter() - t0) * 1e6
         t0 = time.perf_counter()
-        _, it_s = E.sssp(data, mesh, source=0)
+        _, it_s = E.sssp(sdata, source=0)
         t_ss = (time.perf_counter() - t0) * 1e6
         t0 = time.perf_counter()
-        _, it_w = E.wcc(data, mesh)
+        _, it_w = E.wcc(sdata)
         t_wc = (time.perf_counter() - t0) * 1e6
         emit(
             f"table6/{name}/k{k}",

@@ -16,6 +16,7 @@ from repro.elastic import expert_place as ep
 from repro.elastic import resharder as rs
 from repro.elastic.rescale_exec import ElasticRescaler
 from repro.graphs import engine as E
+from repro.launch import mesh as MM
 
 from .common import emit
 
@@ -28,7 +29,7 @@ def _executed_stats(rescaler: ElasticRescaler, k_old: int, k_new: int):
     synthetic (the rescaler moves ranges, never reads endpoints); recheck is
     skipped — graph quality metrics are meaningless for checkpoint blocks."""
     ids = np.zeros(PROXY_ROWS, dtype=np.int64)
-    data = E.pack_ordered(ids, ids, 1, k_old)
+    data = E.pack_ordered_sharded(ids, ids, 1, k_old, MM.make_graph_mesh(1))
     return rescaler.execute(data, rescaler.plan(data, k_new), recheck=False)[1]
 
 

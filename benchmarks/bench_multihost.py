@@ -27,7 +27,8 @@ import time
 import numpy as np
 
 from repro.core import cep, ordering
-from repro.elastic.rescale_exec import EDGE_BYTES, ElasticRescaler
+from repro.elastic.rescale_exec import ElasticRescaler
+from repro.graphs.engine import EDGE_BYTES
 
 from .common import bench_graph, emit, emit_peak_rss, parse_peak_rss, peak_rss_mb
 

@@ -23,8 +23,10 @@ import time
 import numpy as np
 
 from repro.core import baselines, cep, ordering
-from repro.elastic.rescale_exec import EDGE_BYTES, ElasticRescaler, plan_segments
+from repro.elastic.rescale_exec import ElasticRescaler, plan_segments
 from repro.graphs import engine as E
+from repro.graphs.engine import EDGE_BYTES
+from repro.launch import mesh as MM
 
 from .common import bench_graph, emit, peak_rss_mb
 
@@ -59,6 +61,7 @@ def run(scale: int = 12, edge_factor: int = 12, out_path: str = "BENCH_rescale.j
     src, dst = g.src[order], g.dst[order]
     n = g.num_edges
     rescaler = ElasticRescaler()
+    mesh1 = MM.make_graph_mesh(1)
     record = {
         "graph": {"rmat_scale": scale, "edge_factor": edge_factor, "seed": 0,
                   "num_vertices": g.num_vertices, "num_edges": n},
@@ -69,7 +72,7 @@ def run(scale: int = 12, edge_factor: int = 12, out_path: str = "BENCH_rescale.j
     for k in (4, 8, 16, 32, 64, 128):
         k_new = k + 1  # the paper's elasticity step (Cor. 1: ≈ |E|/2 moves)
         plan = cep.scale_plan(n, k, k_new)
-        pack = lambda: E.pack_ordered(src, dst, g.num_vertices, k)
+        pack = lambda: E.pack_ordered_sharded(src, dst, g.num_vertices, k, mesh1)
         stats = _best_exec(rescaler, pack, plan)
         hash_moved, hash_s = _hash_baseline(g, k, k_new)
         row = {
@@ -95,14 +98,14 @@ def run(scale: int = 12, edge_factor: int = 12, out_path: str = "BENCH_rescale.j
              f"moved={hash_moved};frac={row['hash_moved_frac']:.3f}")
 
     # ---- acceptance round-trip: 8 → 12 → 8, bit-identical both ways -------
-    d8 = E.pack_ordered(src, dst, g.num_vertices, 8)
+    d8 = E.pack_ordered_sharded(src, dst, g.num_vertices, 8, mesh1)
     plan_out = cep.scale_plan(n, 8, 12)
     d12, s_out = rescaler.execute(d8, plan_out, verify=True)
     back, s_in = rescaler.rescale(d12, 8, verify=True)
     orig = E.pack_ordered(src, dst, g.num_vertices, 8)
+    back = E.unshard_engine_data(back)
     identical = bool(
-        np.array_equal(np.asarray(back.edges), np.asarray(orig.edges))
-        and np.array_equal(np.asarray(back.mask), np.asarray(orig.mask))
+        np.array_equal(back.edges, orig.edges) and np.array_equal(back.mask, orig.mask)
     )
     thm2 = cep.migration_cost_theorem2(n, 8, 4)
     # Thm. 2 is a closed-form approximation with O(k) rounding slack; the
@@ -147,7 +150,6 @@ def run_multidevice(scale: int = 12, edge_factor: int = 12) -> dict:
     devices (the parent spawns one via _spawn_multidevice)."""
     import jax
 
-    from repro.launch import mesh as MM
     from repro.launch import sharding as SH
 
     g = bench_graph(scale, edge_factor)

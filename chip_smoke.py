@@ -53,8 +53,9 @@ from repro.core.graph import Graph  # noqa: E402
 from repro.core import cep, metrics  # noqa: E402
 from repro.data import shards as DS  # noqa: E402
 from repro.elastic import controller as EC  # noqa: E402
-from repro.elastic.rescale_exec import EDGE_BYTES, ElasticRescaler  # noqa: E402
+from repro.elastic.rescale_exec import ElasticRescaler  # noqa: E402
 from repro.graphs import engine as GE  # noqa: E402
+from repro.graphs.engine import EDGE_BYTES  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.launch import mesh as MM  # noqa: E402
 from repro.stream import IncrementalOrderer, StreamingEngine, SyntheticStream  # noqa: E402
@@ -290,8 +291,8 @@ def rescale_roundtrip(data, host_pack, plan_out, plan_in) -> dict:
         facts[f"{name}_migrated_bytes"] = st.migrated_bytes
     back = GE.unshard_engine_data(data)
     check(
-        np.array_equal(np.asarray(back.edges), np.asarray(host_pack.edges))
-        and np.array_equal(np.asarray(back.mask), np.asarray(host_pack.mask)),
+        np.array_equal(back.edges, host_pack.edges)
+        and np.array_equal(back.mask, host_pack.mask),
         "8->12->8 round trip is not byte-identical to pack_ordered",
     )
     return facts

@@ -14,7 +14,7 @@ from repro.launch import mesh as MM
 
 def main() -> None:
     g = rmat_graph(scale=12, edge_factor=10, seed=1)
-    mesh = MM.make_test_mesh(1, 1)  # run with XLA_FLAGS=...device_count=8 for real shards
+    mesh = MM.make_graph_mesh()  # run with XLA_FLAGS=...device_count=8 for real shards
     k = 8
     print(f"graph: |V|={g.num_vertices:,} |E|={g.num_edges:,}, k={k}")
 
@@ -26,8 +26,9 @@ def main() -> None:
     }
     results = {}
     for name, data in variants.items():
+        sdata = E.shard_engine_data(data, mesh)
         t0 = time.time()
-        pr = E.pagerank(data, mesh, iterations=20)
+        pr = E.pagerank(sdata, iterations=20)
         dt = time.time() - t0
         com = E.comm_volume_per_iteration(data)
         results[name] = np.asarray(pr)

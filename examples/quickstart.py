@@ -41,10 +41,12 @@ def main() -> None:
     #    slice copies over the packed (k, E_max, 2) engine buffers.
     from repro.elastic.rescale_exec import ElasticRescaler
     from repro.graphs import engine as E
+    from repro.launch import mesh as MM
 
+    mesh1 = MM.make_graph_mesh(1)
     rescaler = ElasticRescaler()
-    rescaler.execute(E.pack_ordered(src, dst, g.num_vertices, 16), plan)  # warm the jit
-    data = E.pack_ordered(src, dst, g.num_vertices, 16)
+    rescaler.execute(E.pack_ordered_sharded(src, dst, g.num_vertices, 16, mesh1), plan)  # warm
+    data = E.pack_ordered_sharded(src, dst, g.num_vertices, 16, mesh1)
     new_data, stats = rescaler.execute(data, plan, verify=True)
     print(f"executed 16→17 in {stats.elapsed_s*1e3:.2f}ms: "
           f"{stats.migrated_bytes:,}B over {stats.copy_ops} slice copies, "
@@ -59,11 +61,10 @@ def main() -> None:
     #    below never ships a span re-upload (span_repair="host" restores the
     #    old behavior). (Full scenario + committed numbers:
     #    python -m benchmarks.run stream → BENCH_stream.json.)
-    from repro.launch import mesh as MM
     from repro.stream import IncrementalOrderer, StreamingEngine, SyntheticStream
 
     orderer = IncrementalOrderer(src, dst, g.num_vertices, regions=8)
-    engine = StreamingEngine(orderer, MM.make_graph_mesh(1))
+    engine = StreamingEngine(orderer, mesh1)
     stream = SyntheticStream(g, batch_size=256, seed=1)
     for _ in range(4):
         st = engine.ingest(stream.batch(), verify=True)

@@ -169,7 +169,7 @@ class ElasticController:
         self.events: list = []  # ScaleEvents + IngestEvents, ordered by seq
         self._rescaler = rescaler
         self._seq = 0  # one counter for all event kinds
-        self.engine_data = None  # packed EngineData migrated on scale events
+        self.engine_data = None  # ShardedEngineData migrated on scale events
         self.stream = None  # StreamingEngine: scale events + ingest run on it
         self.autoscaler = None  # AutoscalePolicy consulted by autoscale()
         self._backlog = 0  # externally-reported work backlog (serve queue)
@@ -293,26 +293,20 @@ class ElasticController:
                 )
         return None
 
-    def attach_engine(self, data, mesh=None) -> None:
-        """Attach packed graph-engine state (``engine.pack_ordered`` layout).
+    def attach_engine(self, sdata) -> None:
+        """Attach a device pack (``engine.ShardedEngineData`` in the
+        ``pack_ordered`` layout; shard a host pack first with
+        ``engine.shard_engine_data(pack, launch.mesh.make_graph_mesh(1))`` or
+        a mesh of your own).
 
-        With an engine attached, every rescale decision is *executed*: the
-        emitted event carries ``executed=True`` and ``self.engine_data`` is
-        replaced by the migrated k_new engine data (stats appended to
+        With an engine attached, every rescale decision is *executed* as an
+        on-mesh migration: the emitted event carries ``executed=True`` and
+        the device-to-device traffic it generated
+        (``ScaleEvent.cross_device_bytes``), and ``self.engine_data`` is
+        replaced by the migrated k_new pack (stats appended to
         ``self.rescale_stats``) — not just a plan.
-
-        Passing ``mesh`` (a ``graph``-axis mesh from launch.mesh.make_graph_mesh)
-        distributes the pack over its devices first, so every subsequent scale
-        event is executed as an on-mesh migration and reports the device-to-
-        device traffic it actually generated (``ScaleEvent.cross_device_bytes``).
-        A ``ShardedEngineData`` may also be attached directly.
         """
-        if mesh is not None:
-            from ..graphs import engine as graph_engine
-
-            if not isinstance(data, graph_engine.ShardedEngineData):
-                data = graph_engine.shard_engine_data(data, mesh)
-        self.engine_data = data
+        self.engine_data = sdata
 
     def attach_stream(self, stream) -> None:
         """Attach a live ``stream.ingest.StreamingEngine``.

@@ -8,15 +8,12 @@ buffer donated — so executing a rescale costs O(overlay ranges) program size
 and moves exactly the Thm.-2-minimal edge ranges across partitions, instead of
 re-running any partitioner or re-packing from the host.
 
-The same program executes on both layouts (DESIGN.md §6):
-
-* ``EngineData`` — the replicated single-buffer pack. Partition p is row p;
-  every copy is device-local. This is the degenerate mesh-of-1 case.
-* ``ShardedEngineData`` — the pack distributed over a mesh's ``graph`` axis.
-  Rows are permuted device-major (partition p on device p % g), the output
-  carries the k_new NamedSharding, and XLA's SPMD partitioner turns exactly
-  the plan's cross-device boundary ranges into device-to-device transfers
-  while stays and local shifts compile to shard-local slice copies.
+The program executes on the device pack, ``ShardedEngineData`` (DESIGN.md
+§6): rows are permuted device-major (partition p on device p % g), the output
+carries the k_new NamedSharding, and XLA's SPMD partitioner turns exactly the
+plan's cross-device boundary ranges into device-to-device transfers while
+stays and local shifts compile to shard-local slice copies. A mesh of 1 is
+the degenerate case: every copy is device-local.
 
 Cost accounting distinguishes what a real multi-host deployment would see:
 
@@ -39,7 +36,6 @@ Cost accounting distinguishes what a real multi-host deployment would see:
 """
 from __future__ import annotations
 
-import collections
 import dataclasses
 import time
 
@@ -50,116 +46,22 @@ import numpy as np
 from ..compat import donate_jit
 from ..core import cep, metrics
 from ..graphs import engine as graph_engine
+from ..graphs.engine import EDGE_BYTES
 from ..launch import sharding as SH
 from ..obs import metrics as OM
 from ..obs import trace as OT
+from ..program_cache import ProgramCache
 
 __all__ = [
-    "EDGE_BYTES",
-    "ProgramCache",
     "RescaleStats",
     "ElasticRescaler",
     "plan_segments",
     "cross_process_plan_edges",
 ]
 
-EDGE_BYTES = 8  # (src, dst) int32 per packed edge row
-
-
-class ProgramCache:
-    """Bounded LRU of jitted device programs keyed by their static shape/mesh
-    signature. Keys are KIND-prefixed tuples (("migrate", ...), ("counts",
-    ...), ("scatter", ...), ("compact", ...), ("span_repair", ...)) so every
-    program family of one runtime component shares a single cache — a
-    long-lived controller oscillating between configurations pays tracing
-    once per signature without any cache growing without limit, and
-    ``program_cache_size`` bounds ALL of a component's cached programs at
-    once (ElasticRescaler: migrate + counts; StreamingEngine: scatter +
-    compact + span_repair + full_reorder + splice).
-
-    Per-kind hit/miss/eviction counters (``counters`` / ``counters_snapshot``)
-    make the cache's behavior auditable from event logs: a ``get`` returning a
-    program is a hit, a ``get`` returning None a miss (the caller compiles and
-    ``put``s), and ``put`` evicting an LRU victim an eviction — so the stream
-    bench can PROVE an escalation never paid a compile (its kind's miss count
-    is flat across the monitored stream) instead of asserting it by eye."""
-
-    def __init__(self, size: int):
-        if size < 1:
-            raise ValueError("program_cache_size must be >= 1")
-        self.size = int(size)
-        self._programs: collections.OrderedDict = collections.OrderedDict()
-        # kind (key[0] for tuple keys, "?" otherwise) → {hits, misses, evictions}
-        self.counters: dict = {}
-        self._counters_shared = False  # a snapshot aliases self.counters
-
-    def __len__(self) -> int:
-        return len(self._programs)
-
-    def __contains__(self, key) -> bool:
-        return key in self._programs
-
-    def __iter__(self):
-        return iter(self._programs)  # keys, least- to most-recently used
-
-    @staticmethod
-    def _kind(key) -> str:
-        return str(key[0]) if isinstance(key, tuple) and key else "?"
-
-    def _count(self, key, event: str) -> None:
-        if self._counters_shared:
-            # Copy-on-WRITE: a snapshot handed out earlier aliases the live
-            # dicts — clone before mutating so every outstanding snapshot
-            # stays frozen at its emit-time values.
-            self.counters = {kind: dict(c) for kind, c in self.counters.items()}
-            self._counters_shared = False
-        c = self.counters.setdefault(
-            self._kind(key), {"hits": 0, "misses": 0, "evictions": 0}
-        )
-        c[event] += 1
-
-    def counters_snapshot(self) -> dict:
-        """Per-kind counters, isolated from later cache activity — safe to
-        attach to events. Lazily: the LIVE mapping is returned and the cache
-        clones it before its next mutation (copy-on-write), so the per-event
-        hot path (every IngestEvent snapshots) costs a flag set, not a deep
-        copy per batch. Callers must treat the result as immutable."""
-        self._counters_shared = True
-        return self.counters
-
-    def get(self, key):
-        cached = self._programs.get(key)
-        if cached is not None:
-            self._programs.move_to_end(key)
-            self._count(key, "hits")
-        else:
-            self._count(key, "misses")
-        return cached
-
-    def touch(self, key) -> bool:
-        """Refresh recency if present (counted as a hit). Unlike ``get``, an
-        absent key counts NOTHING — warm-up helpers probe with this before
-        delegating to the builder (whose own ``get`` miss then counts the
-        compile exactly once, keeping misses == compiles for the bench)."""
-        cached = self._programs.get(key)
-        if cached is not None:
-            self._programs.move_to_end(key)
-            self._count(key, "hits")
-            return True
-        return False
-
-    def put(self, key, value):
-        self._programs[key] = value
-        while len(self._programs) > self.size:
-            victim, _ = self._programs.popitem(last=False)
-            self._count(victim, "evictions")
-        return value
-
 
 def _mesh_processes(mesh) -> int:
-    """Distinct processes behind a mesh (1 for mesh=None / single-process)."""
-    if mesh is None:
-        return 1
+    """Distinct processes behind a mesh (1 on a single-process mesh)."""
     return len(set(SH.device_process_map(mesh).tolist()))
 
 
@@ -215,12 +117,9 @@ class RescaleStats:
 
 
 class ElasticRescaler:
-    """Executes ``cep.ScalePlan``s against packed engine state.
-
-    Accepts both ``EngineData`` (replicated pack; mesh-of-1 degenerate case)
-    and ``ShardedEngineData`` (partitions distributed round-robin over a
-    ``graph`` mesh axis) — one program builder serves both, parameterized only
-    by the row permutation and output sharding.
+    """Executes ``cep.ScalePlan``s against a ``ShardedEngineData`` (partitions
+    distributed round-robin over a ``graph`` mesh axis; a mesh of 1 is the
+    degenerate case).
 
     Jitted migration programs are cached per (num_edges, k_old, k_new, mesh)
     in a bounded LRU (``program_cache_size``) so a controller oscillating
@@ -255,13 +154,13 @@ class ElasticRescaler:
         return self._programs.size
 
     # ------------------------------------------------------------- planning
-    def plan(self, data, k_new: int) -> cep.ScalePlan:
+    def plan(self, data: graph_engine.ShardedEngineData, k_new: int) -> cep.ScalePlan:
         return cep.scale_plan(data.num_edges, data.k, k_new)
 
     # ------------------------------------------------------------ execution
     def execute(
         self,
-        data,
+        data: graph_engine.ShardedEngineData,
         plan: cep.ScalePlan,
         *,
         verify: bool = False,
@@ -270,7 +169,7 @@ class ElasticRescaler:
         """Apply ``plan`` to ``data``; returns ``(new_data, RescaleStats)``.
 
         ``data`` must be CEP-chunked (partition p = ordered range p, as built
-        by ``pack_ordered`` / ``pack_ordered_sharded``). The old edge buffer
+        by ``pack_ordered_sharded``). The old edge buffer
         is donated to the migration program: treat ``data`` as CONSUMED — on
         backends where XLA can alias it, reading ``data.edges`` afterwards
         raises "Array has been deleted".
@@ -283,26 +182,25 @@ class ElasticRescaler:
         ``verify=True`` implies the readback regardless.
         """
         n, k_old, k_new = plan.num_edges, plan.k_old, plan.k_new
-        sharded = isinstance(data, graph_engine.ShardedEngineData)
-        mesh = data.mesh if sharded else None
-        g = SH.graph_axis_size(mesh)
+        mesh = data.mesh
+        g = data.devices
         if data.k != k_old:
             raise ValueError(f"plan is for k_old={k_old} but engine data has k={data.k}")
         if data.num_edges != n:
             raise ValueError(f"plan is for |E|={n} but engine data has |E|={data.num_edges}")
         # Layout check without gathering the full mask: reduce per-row counts
-        # on device (sharded, O(k_pad) ints to host) so recheck=False keeps
-        # the O(overlay-ranges) migration cost on a real mesh. On a
+        # on device (O(k_pad) ints to host) so recheck=False keeps the
+        # O(overlay-ranges) migration cost on a real mesh. On a
         # multi-process mesh the row sums must land replicated before the host
         # can read them (the sharded result spans non-addressable devices);
         # the tiny counts program is cached like the migration programs.
-        if sharded and not data.mask.is_fully_addressable:
+        if not data.mask.is_fully_addressable:
             counts = np.asarray(self._counts_program(data.mask.shape, mesh)(data.mask))
         else:
             counts = np.asarray(jnp.sum(data.mask > 0, axis=1))
         sizes_old = np.diff(cep.chunk_bounds(n, k_old))
         want = np.zeros(counts.shape[0], dtype=sizes_old.dtype)
-        for p in range(k_old):  # padding rows (sharded pack) must stay empty
+        for p in range(k_old):  # padding rows must stay empty
             want[SH.partition_row(p, k_old, g)] = sizes_old[p]
         if not np.array_equal(counts, want):
             raise ValueError(
@@ -328,8 +226,7 @@ class ElasticRescaler:
         # — the verify=True from-scratch oracle.
         readback = recheck or verify
         if readback:
-            flat = graph_engine.unshard_engine_data(data) if sharded else data
-            src_o, dst_o = graph_engine.unpack_ordered(flat)
+            src_o, dst_o = graph_engine.unpack_ordered(graph_engine.unshard_engine_data(data))
         else:
             src_o, dst_o = None, None
 
@@ -353,7 +250,6 @@ class ElasticRescaler:
             rf = float(counts_v.sum()) / float(data.num_vertices)
         else:
             mirrors, rf = -1, float("nan")
-        # Same fields for both layouts (ShardedEngineData keeps its mesh).
         new_data = dataclasses.replace(
             data,
             edges=new_edges,
@@ -368,10 +264,9 @@ class ElasticRescaler:
             # From-scratch pack of the ORIGINAL ordered list at k_new — a
             # mis-routed move segment cannot fool this.
             oracle = graph_engine.pack_ordered(src_o, dst_o, data.num_vertices, k_new)
-            got = graph_engine.unshard_engine_data(new_data) if sharded else new_data
+            got = graph_engine.unshard_engine_data(new_data)
             if not (
-                np.array_equal(np.asarray(oracle.edges), np.asarray(got.edges))
-                and np.array_equal(np.asarray(oracle.mask), np.asarray(got.mask))
+                np.array_equal(oracle.edges, got.edges) and np.array_equal(oracle.mask, got.mask)
             ):
                 raise AssertionError("executed rescale does not match from-scratch pack")
             oracle_checked = True
@@ -384,7 +279,7 @@ class ElasticRescaler:
 
     def rescale(
         self,
-        data,
+        data: graph_engine.ShardedEngineData,
         k_new: int,
         *,
         verify: bool = False,
@@ -423,9 +318,8 @@ class ElasticRescaler:
         sizes_new = np.diff(bn)
         e_max_new = int(sizes_new.max())
         k_pad_new = SH.padded_partition_count(k_new, g)
-        # Device-major row of each partition in the old / new layouts. On a
-        # mesh of 1 both are the identity and the program below is exactly the
-        # historical single-buffer slice-copy program.
+        # Device-major row of each partition in the old / new layouts (the
+        # identity on a mesh of 1).
         row_old = [SH.partition_row(p, k_old, g) for p in range(k_old)]
         row_new = [SH.partition_row(p, k_new, g) for p in range(k_new)]
         segments = plan_segments(plan)
@@ -472,12 +366,10 @@ class ElasticRescaler:
                 new = new.at[row_new[d], lo - int(bn[d]) : hi - int(bn[d]), :].set(seg)
             return new, mask_new
 
-        jit_kwargs: dict = {}
-        if mesh is not None:
-            s_edges, s_mask, _ = SH.engine_shardings(mesh)
-            jit_kwargs["out_shardings"] = (s_edges, s_mask)
+        s_edges, s_mask, _ = SH.engine_shardings(mesh)
+        out_shardings = (s_edges, s_mask)
         if self.donate:
-            program = donate_jit(migrate, donate_argnums=(0,), **jit_kwargs)
+            program = donate_jit(migrate, donate_argnums=(0,), out_shardings=out_shardings)
         else:
-            program = jax.jit(migrate, **jit_kwargs)
+            program = jax.jit(migrate, out_shardings=out_shardings)
         return self._programs.put(key, (program, stats))

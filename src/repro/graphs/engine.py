@@ -8,16 +8,20 @@ with psum/pmin. Per-iteration *communication volume* is reported with the
 paper's own mirror metric (Σ_p |V(E_p)| − |V|), which is what the partition
 quality controls on a real sparse-exchange system.
 
-Two layouts (DESIGN.md §6):
+One device layout and one host oracle (DESIGN.md §6):
 
-* ``EngineData`` — the replicated pack: one (k, E_max, 2) buffer, partition p
-  at row p. Fine on one device; the ``data`` mesh axis splits rows.
-* ``ShardedEngineData`` — the distributed pack: a (k_pad, E_max, 2) buffer
-  carrying a NamedSharding over the ``graph`` mesh axis, rows in device-major
-  round-robin order (partition p on device p % g, at row
-  launch.sharding.partition_row(p, k, g)). GAS iteration shard_maps directly
-  over the sharded rows, and elastic/rescale_exec.py executes ScalePlans on it
+* ``ShardedEngineData`` — the only pack a device program reads: a
+  (k_pad, E_max, 2) buffer carrying a NamedSharding over the ``graph`` mesh
+  axis, rows in device-major round-robin order (partition p on device p % g,
+  at row launch.sharding.partition_row(p, k, g)). A mesh of 1 is its
+  degenerate case. Every query kind is one cached program over it
+  (``query_program``), and elastic/rescale_exec.py executes ScalePlans on it
   as on-mesh migrations.
+* ``EngineData`` — the host oracle: numpy (k, E_max, 2) buffers, partition p
+  at row p. The builders (``pack_ordered``, ``pack_slots``, …) return it,
+  ``shard_engine_data`` is the one way it reaches a device, and
+  ``unshard_engine_data`` reads a device pack back into it for the
+  byte-for-byte comparisons of the tests and ``verify_bit_identity``.
 """
 from __future__ import annotations
 
@@ -27,7 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..compat import shard_map
@@ -35,14 +38,20 @@ from ..core import cep, metrics
 from ..core.graph import Graph
 from ..launch import sharding as SH
 
-AXIS = "data"
+EDGE_BYTES = 8  # (src, dst) int32 per packed edge row
 
 
 @dataclasses.dataclass(frozen=True)
 class EngineData:
-    edges: jnp.ndarray  # (k, E_max, 2) int32 — undirected, both endpoints
-    mask: jnp.ndarray  # (k, E_max) f32 1/0 padding mask
-    degrees: jnp.ndarray  # (V,) f32
+    """Host oracle pack: partition p's edges at row p, padded and masked.
+
+    Tests and ``verify_bit_identity`` compare device packs against it byte
+    for byte (after ``unshard_engine_data``); ``shard_engine_data`` commits
+    it to a mesh. No device program reads it."""
+
+    edges: np.ndarray  # (k, E_max, 2) int32 — undirected, both endpoints
+    mask: np.ndarray  # (k, E_max) f32 1/0 padding mask
+    degrees: np.ndarray  # (V,) f32
     num_vertices: int
     k: int
     mirrors: int  # Σ_p |V(E_p)| − |V(E)| — the paper's comm-volume metric
@@ -71,9 +80,9 @@ def build_engine_data(g: Graph, part: np.ndarray, k: int) -> EngineData:
     mir = metrics.mirror_count(g.src, g.dst, part, k, g.num_vertices)
     rf = metrics.replication_factor(g.src, g.dst, part, k, g.num_vertices)
     return EngineData(
-        edges=jnp.asarray(edges),
-        mask=jnp.asarray(mask),
-        degrees=jnp.asarray(deg),
+        edges=edges,
+        mask=mask,
+        degrees=deg,
         num_vertices=g.num_vertices,
         k=k,
         mirrors=mir,
@@ -123,9 +132,9 @@ def pack_ordered(
     mir = metrics.mirror_count_ordered(src_ordered, dst_ordered, k, num_vertices)
     rf = metrics.replication_factor_ordered(src_ordered, dst_ordered, k, num_vertices)
     return EngineData(
-        edges=jnp.asarray(edges),
-        mask=jnp.asarray(mask),
-        degrees=jnp.asarray(deg),
+        edges=edges,
+        mask=mask,
+        degrees=deg,
         num_vertices=num_vertices,
         k=k,
         mirrors=mir,
@@ -136,10 +145,9 @@ def pack_ordered(
 
 def unpack_ordered(data: EngineData) -> tuple[np.ndarray, np.ndarray]:
     """Host-side inverse of pack_ordered: the flat ordered (src, dst) lists."""
-    edges = np.asarray(data.edges)
-    counts = np.asarray(data.mask).astype(bool).sum(axis=1)
-    src = np.concatenate([edges[p, : counts[p], 0] for p in range(data.k)])
-    dst = np.concatenate([edges[p, : counts[p], 1] for p in range(data.k)])
+    counts = data.mask.astype(bool).sum(axis=1)
+    src = np.concatenate([data.edges[p, : counts[p], 0] for p in range(data.k)])
+    dst = np.concatenate([data.edges[p, : counts[p], 1] for p in range(data.k)])
     return src, dst
 
 
@@ -150,7 +158,8 @@ def cep_engine_data(g: Graph, order: np.ndarray, k: int) -> EngineData:
 # ------------------------------------------------------------ sharded layout
 @dataclasses.dataclass(frozen=True)
 class ShardedEngineData:
-    """EngineData distributed over the ``graph`` axis of a mesh.
+    """The device pack: an EngineData distributed over the ``graph`` axis of
+    a mesh.
 
     ``edges``/``mask`` are (k_pad, E_max, 2) / (k_pad, E_max) arrays committed
     with a NamedSharding that splits the leading axis over ``graph``; rows are
@@ -187,7 +196,8 @@ class ShardedEngineData:
 
 
 def shard_engine_data(data: EngineData, mesh) -> ShardedEngineData:
-    """Distribute a packed EngineData over ``mesh``'s ``graph`` axis.
+    """Distribute a host EngineData over ``mesh``'s ``graph`` axis, with one
+    ``put_global`` per array — the only way a host pack reaches a device.
 
     Works on multi-process meshes too: the host pack must then be replicated
     on every process (graphs are built deterministically from the seed, or
@@ -202,13 +212,13 @@ def shard_engine_data(data: EngineData, mesh) -> ShardedEngineData:
     edges = np.zeros((k_pad, e_max, 2), dtype=np.int32)
     mask = np.zeros((k_pad, e_max), dtype=np.float32)
     rows = [SH.partition_row(p, k, g) for p in range(k)]
-    edges[rows] = np.asarray(data.edges)
-    mask[rows] = np.asarray(data.mask)
+    edges[rows] = data.edges
+    mask[rows] = data.mask
     s_edges, s_mask, s_vert = SH.engine_shardings(mesh)
     return ShardedEngineData(
         edges=MH.put_global(edges, s_edges),
         mask=MH.put_global(mask, s_mask),
-        degrees=MH.put_global(np.asarray(data.degrees), s_vert),
+        degrees=MH.put_global(data.degrees, s_vert),
         num_vertices=data.num_vertices,
         k=k,
         mesh=mesh,
@@ -220,15 +230,15 @@ def shard_engine_data(data: EngineData, mesh) -> ShardedEngineData:
 
 def unshard_engine_data(sdata: ShardedEngineData) -> EngineData:
     """Host-side inverse of shard_engine_data: gather + un-permute rows back to
-    the partition-major replicated pack (the bit-identity oracle layout). On a
+    the partition-major host pack (the bit-identity oracle layout). On a
     multi-process mesh the gather is a collective (every process must call)."""
     from ..launch import multihost as MH
 
     rows = [SH.partition_row(p, sdata.k, sdata.devices) for p in range(sdata.k)]
     return EngineData(
-        edges=jnp.asarray(MH.host_read(sdata.edges)[rows]),
-        mask=jnp.asarray(MH.host_read(sdata.mask)[rows]),
-        degrees=jnp.asarray(MH.host_read(sdata.degrees)),
+        edges=MH.host_read(sdata.edges)[rows],
+        mask=MH.host_read(sdata.mask)[rows],
+        degrees=MH.host_read(sdata.degrees),
         num_vertices=sdata.num_vertices,
         k=sdata.k,
         mirrors=sdata.mirrors,
@@ -288,9 +298,9 @@ def pack_slots(
     # Quality metrics are monitored incrementally by the orderer, not carried
     # on the pack (same convention as ElasticRescaler's recheck=False).
     return EngineData(
-        edges=jnp.asarray(edges),
-        mask=jnp.asarray(mask),
-        degrees=jnp.asarray(deg),
+        edges=edges,
+        mask=mask,
+        degrees=deg,
         num_vertices=num_vertices,
         k=k,
         mirrors=-1,
@@ -375,113 +385,9 @@ def pack_slots_sharded_stream(
     )
 
 
-def _axis_and_mesh(data, mesh):
-    """GAS dispatch: ShardedEngineData iterates over its own ``graph`` mesh;
-    the replicated pack keeps the historical ``data``-axis path."""
-    if isinstance(data, ShardedEngineData):
-        return SH.GRAPH_AXIS, (mesh if mesh is not None else data.mesh)
-    if mesh is None:
-        raise ValueError("EngineData (replicated pack) requires an explicit mesh")
-    return AXIS, mesh
-
-
 def _sharded(fn, mesh, axis, extra_in=(), extra_out=P()):
     in_specs = (P(axis, None, None), P(axis, None)) + tuple(extra_in)
     return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=extra_out, check_vma=False)
-
-
-def pagerank(data, mesh=None, *, iterations: int = 20, damping: float = 0.85):
-    axis, mesh = _axis_and_mesh(data, mesh)
-    v = data.num_vertices
-    deg = jnp.maximum(data.degrees, 1.0)
-
-    def local(edges, mask, x):
-        e = edges.reshape(-1, 2)  # all chunks owned by this device
-        m = mask.reshape(-1)
-        contrib = x / deg
-        y = jnp.zeros((v,), jnp.float32)
-        # Undirected: each edge pushes both ways (vertex-cut GAS scatter).
-        y = y.at[e[:, 1]].add(contrib[e[:, 0]] * m)
-        y = y.at[e[:, 0]].add(contrib[e[:, 1]] * m)
-        return lax.psum(y, axis)
-
-    step = _sharded(local, mesh, axis, extra_in=(P(),), extra_out=P())
-    dangling = data.degrees == 0
-
-    def body(x, _):
-        y = step(data.edges, data.mask, x)
-        # Dangling vertices spread their mass uniformly (networkx convention).
-        dm = jnp.sum(jnp.where(dangling, x, 0.0))
-        return (1 - damping) / v + damping * (y + dm / v), None
-
-    x0 = jnp.full((v,), 1.0 / v, jnp.float32)
-    with mesh:
-        x, _ = jax.jit(lambda x0: lax.scan(body, x0, None, length=iterations))(x0)
-    return x
-
-
-def sssp(data, mesh=None, *, source: int = 0, max_iters: int = 64):
-    axis, mesh = _axis_and_mesh(data, mesh)
-    v = data.num_vertices
-    inf = jnp.float32(1e9)
-
-    def local(edges, mask, dist):
-        e = edges.reshape(-1, 2)
-        m = mask.reshape(-1) > 0
-        cand = jnp.full((v,), inf)
-        du = jnp.where(m, dist[e[:, 0]] + 1.0, inf)
-        dv = jnp.where(m, dist[e[:, 1]] + 1.0, inf)
-        cand = cand.at[e[:, 1]].min(du)
-        cand = cand.at[e[:, 0]].min(dv)
-        return lax.pmin(cand, axis)
-
-    step = _sharded(local, mesh, axis, extra_in=(P(),), extra_out=P())
-
-    def cond(state):
-        _, changed, it = state
-        return jnp.logical_and(changed, it < max_iters)
-
-    def body(state):
-        dist, _, it = state
-        nd = jnp.minimum(dist, step(data.edges, data.mask, dist))
-        return nd, jnp.any(nd < dist), it + 1
-
-    d0 = jnp.full((v,), inf).at[source].set(0.0)
-    with mesh:
-        dist, _, iters = jax.jit(lambda d: lax.while_loop(cond, body, (d, jnp.bool_(True), 0)))(d0)
-    return dist, int(iters)
-
-
-def wcc(data, mesh=None, *, max_iters: int = 64):
-    axis, mesh = _axis_and_mesh(data, mesh)
-    v = data.num_vertices
-
-    def local(edges, mask, lab):
-        e = edges.reshape(-1, 2)
-        m = mask.reshape(-1) > 0
-        big = jnp.float32(1e9)
-        cand = jnp.full((v,), big)
-        lu = jnp.where(m, lab[e[:, 0]], big)
-        lv = jnp.where(m, lab[e[:, 1]], big)
-        cand = cand.at[e[:, 1]].min(lu)
-        cand = cand.at[e[:, 0]].min(lv)
-        return lax.pmin(cand, axis)
-
-    step = _sharded(local, mesh, axis, extra_in=(P(),), extra_out=P())
-
-    def cond(state):
-        _, changed, it = state
-        return jnp.logical_and(changed, it < max_iters)
-
-    def body(state):
-        lab, _, it = state
-        nl = jnp.minimum(lab, step(data.edges, data.mask, lab))
-        return nl, jnp.any(nl < lab), it + 1
-
-    l0 = jnp.arange(v, dtype=jnp.float32)
-    with mesh:
-        lab, _, iters = jax.jit(lambda l: lax.while_loop(cond, body, (l, jnp.bool_(True), 0)))(l0)
-    return lab, int(iters)
 
 
 def comm_volume_per_iteration(data: EngineData, bytes_per_value: int = 8) -> int:
@@ -490,22 +396,16 @@ def comm_volume_per_iteration(data: EngineData, bytes_per_value: int = 8) -> int
 
 
 # --------------------------------------------------------------------------
-# Cached pure-operand query programs (the serving path, launch/serve.py).
-#
-# The module-level entry points above close over the pack and build a fresh
-# ``jax.jit(lambda ...)`` per call — every call is a new callable, so every
-# call retraces. Fine for a benchmark that runs PageRank once; fatal for a
-# front end answering thousands of queries. ``query_program`` returns a
-# callable that takes the pack OPERANDS (edges, mask, degrees[, source])
-# explicitly: the jit compiles once per operand shape, so one program serves
-# every query against any pack of that layout — including the packs that
-# rescale / async full rebuild swap underneath a live StreamingEngine, which
-# only retrace when (k_pad, e_cap) actually changes. SSSP's source is a
-# traced int32 operand, so querying a new source is a cache hit, not a
-# retrace. Programs iterate over the ``graph`` mesh axis (the sharded-pack
-# layout both ShardedEngineData and StreamingEngine.data use). Their jitted
-# functions are named ``query_<kind>``, so a profiler trace reads them as
-# ``jit_query_<kind>``.
+# Query programs (the serving path, launch/serve.py, and ``pagerank`` /
+# ``sssp`` / ``wcc`` below). ``query_program`` returns a callable that takes
+# the pack OPERANDS (edges, mask, degrees[, source]) explicitly: the jit
+# compiles once per operand shape, so one program serves every query against
+# any pack of that layout — including the packs that rescale / async full
+# rebuild swap underneath a live StreamingEngine, which only retrace when
+# (k_pad, e_cap) actually changes. SSSP's source is a traced int32 operand,
+# so querying a new source is a cache hit, not a retrace. Programs iterate
+# over the ``graph`` mesh axis. Their jitted functions are named
+# ``query_<kind>``, so a profiler trace reads them as ``jit_query_<kind>``.
 
 
 def _pagerank_program(v: int, mesh, axis: str, iterations: int, damping: float):
@@ -658,3 +558,28 @@ def query_program(
         raise ValueError(f"unknown query kind {kind!r} (expected one of {QUERY_KINDS})")
     _QUERY_PROGRAMS[key] = prog
     return prog
+
+
+def pagerank(sdata: ShardedEngineData, *, iterations: int = 20, damping: float = 0.85):
+    """PageRank ranks (V,) of the pack, through the cached ``query_program``."""
+    prog = query_program(
+        "pagerank", num_vertices=sdata.num_vertices, mesh=sdata.mesh,
+        iterations=iterations, damping=damping,
+    )
+    return prog(sdata.edges, sdata.mask, sdata.degrees)
+
+
+def sssp(sdata: ShardedEngineData, *, source: int = 0, max_iters: int = 64):
+    """Unit-weight SSSP from ``source``: ``(dist, iters)``."""
+    prog = query_program(
+        "sssp", num_vertices=sdata.num_vertices, mesh=sdata.mesh, max_iters=max_iters
+    )
+    return prog(sdata.edges, sdata.mask, source)
+
+
+def wcc(sdata: ShardedEngineData, *, max_iters: int = 64):
+    """Min-label connected components: ``(labels, iters)``."""
+    prog = query_program(
+        "wcc", num_vertices=sdata.num_vertices, mesh=sdata.mesh, max_iters=max_iters
+    )
+    return prog(sdata.edges, sdata.mask)

@@ -5,9 +5,9 @@ The host ``IncrementalOrderer`` owns the ordered slot array; the
 partition p holds region p's slots (``graphs/engine.py pack_slots`` layout:
 occupied slots keep their column, gaps are masked, one trailing scratch
 column). Three jitted device program families — all in one bounded
-kind-prefixed ``ProgramCache`` LRU, the same container the migration programs
-of elastic/rescale_exec.py use — keep the mirror current without ever
-re-packing from the host:
+kind-prefixed ``ProgramCache`` LRU (program_cache.py), the same container the
+migration programs of elastic/rescale_exec.py use — keep the mirror current
+without ever re-packing from the host:
 
 * **scatter** (ingest): each drained ``SlotOp`` becomes one (row, col) write
   of the edge values + mask bit, plus a scatter-add of the per-vertex degree
@@ -74,13 +74,14 @@ from jax.sharding import PartitionSpec as P
 from .. import compat
 from ..compat import donate_jit
 from ..core import cep
-from ..elastic.rescale_exec import EDGE_BYTES, ProgramCache
 from ..graphs import engine as graph_engine
+from ..graphs.engine import EDGE_BYTES
 from ..kernels import full_reorder as FRK
 from ..kernels import span_reorder as SRK
 from ..launch import sharding as SH
 from ..obs import metrics as OM
 from ..obs import trace as OT
+from ..program_cache import ProgramCache
 from .incremental import IncrementalOrderer
 from .updates import EdgeUpdateBatch
 
@@ -292,13 +293,15 @@ class StreamingEngine:
 
     def oracle_pack(self) -> graph_engine.EngineData:
         """Host-side bit-identity oracle: pack_slots of the current host
-        slot array (what the device buffers must equal after unshard)."""
+        slot array (what the device buffers must equal after unshard), in
+        numpy."""
         o = self.orderer
         return graph_engine.pack_slots(
             o.slot_src, o.slot_dst, o.slot_valid, o.regions, o.num_vertices
         )
 
     def _upload(self) -> graph_engine.ShardedEngineData:
+        """Commit the host pack to the mesh: one transfer per array."""
         return graph_engine.shard_engine_data(self.oracle_pack(), self.mesh)
 
     def _stream_upload(self) -> graph_engine.ShardedEngineData:
@@ -498,9 +501,9 @@ class StreamingEngine:
         got = graph_engine.unshard_engine_data(self.data)
         want = self.oracle_pack()
         if not (
-            np.array_equal(np.asarray(got.edges), np.asarray(want.edges))
-            and np.array_equal(np.asarray(got.mask), np.asarray(want.mask))
-            and np.array_equal(np.asarray(got.degrees), np.asarray(want.degrees))
+            np.array_equal(got.edges, want.edges)
+            and np.array_equal(got.mask, want.mask)
+            and np.array_equal(got.degrees, want.degrees)
         ):
             raise AssertionError("sharded streaming pack diverged from the host slot oracle")
         return True

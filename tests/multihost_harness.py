@@ -47,8 +47,9 @@ import jax  # noqa: E402
 from repro.core import cep, ordering  # noqa: E402
 from repro.core.graph import rmat_graph  # noqa: E402
 from repro.elastic import controller as ec  # noqa: E402
-from repro.elastic.rescale_exec import EDGE_BYTES, ElasticRescaler  # noqa: E402
+from repro.elastic.rescale_exec import ElasticRescaler  # noqa: E402
 from repro.graphs import engine as E  # noqa: E402
+from repro.graphs.engine import EDGE_BYTES  # noqa: E402
 from repro.launch import mesh as MM  # noqa: E402
 from repro.launch import sharding as SH  # noqa: E402
 from repro.obs import metrics as OM  # noqa: E402

@@ -92,9 +92,9 @@ def test_pack_slots_layout_roundtrip_resharded(tmp_path, slots, k_new):
     g, o = slots
     pack = GE.pack_slots(o.slot_src, o.slot_dst, o.slot_valid, o.regions, g.num_vertices)
     tree = dict(orderer_tree(g, o), pack={
-        "edges": np.asarray(pack.edges),
-        "mask": np.asarray(pack.mask),
-        "degrees": np.asarray(pack.degrees),
+        "edges": pack.edges,
+        "mask": pack.mask,
+        "degrees": pack.degrees,
     })
     store.save(tree, tmp_path, step=7, k_shards=4)
     restored, bytes_touched = store.restore(tmp_path, 7, k_new=k_new, template=tree)
@@ -109,8 +109,8 @@ def test_pack_slots_layout_roundtrip_resharded(tmp_path, slots, k_new):
         restored["slot"]["src"], restored["slot"]["dst"], restored["slot"]["valid"],
         o.regions, g.num_vertices,
     )
-    np.testing.assert_array_equal(np.asarray(repack.edges), restored["pack"]["edges"])
-    np.testing.assert_array_equal(np.asarray(repack.mask), restored["pack"]["mask"])
+    np.testing.assert_array_equal(repack.edges, restored["pack"]["edges"])
+    np.testing.assert_array_equal(repack.mask, restored["pack"]["mask"])
     assert (bytes_touched == 0) == (k_new == 4)
 
 

@@ -40,6 +40,28 @@ def test_no_direct_shard_map_imports_outside_compat():
     assert not offenders, f"import shard_map via repro.compat, not directly: {offenders}"
 
 
+def test_lower_layers_import_nothing_from_elastic():
+    import re
+
+    # elastic/ (controller, rescaler) sits above the layers it drives; an
+    # import from it in one of them points up. Every spelling: "from
+    # ..elastic.x import", "from .. import elastic", "from repro.elastic",
+    # "import repro.elastic".
+    upward = re.compile(
+        r"from\s+(\.\.|repro\.)elastic\b"
+        r"|from\s+(\.\.|repro)\s+import\s+[^\n]*\belastic\b"
+        r"|import\s+repro\.elastic\b"
+    )
+    root = pathlib.Path(compat.__file__).parent
+    offenders = [
+        str(path)
+        for layer in ("core", "graphs", "kernels", "stream")
+        for path in (root / layer).rglob("*.py")
+        if upward.search(path.read_text())
+    ]
+    assert not offenders, f"modules below elastic/ import from it: {offenders}"
+
+
 def test_jax_version_tuple():
     assert compat.JAX_VERSION[:2] == (0, 9), "support policy: the installed jax 0.9.0"
 

@@ -636,4 +636,4 @@ class TestDrill:
         got = np.stack([rows[r] for r in sorted(rows)])
         # k=4 on g=4 devices: partition_row is the identity, so the global
         # row order IS partition order.
-        assert np.array_equal(got, np.asarray(pack.edges))
+        assert np.array_equal(got, pack.edges)

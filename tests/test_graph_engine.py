@@ -1,5 +1,5 @@
-"""Graph engine validation vs networkx (single device; multi-device variant
-lives in test_multidevice-style subprocess below)."""
+"""Graph engine validation vs networkx on a one-device graph mesh (the
+multi-device variants live in test_multidevice.py's subprocesses)."""
 import networkx as nx
 import numpy as np
 import pytest
@@ -21,7 +21,7 @@ def small():
 
 @pytest.fixture(scope="module")
 def mesh1():
-    return MM.make_test_mesh(data=1, model=1)
+    return MM.make_graph_mesh(1)
 
 
 def _data(g, k=4):
@@ -31,8 +31,8 @@ def _data(g, k=4):
 
 def test_pagerank_matches_networkx(small, mesh1):
     g, nxg = small
-    data = _data(g)
-    pr = np.asarray(E.pagerank(data, mesh1, iterations=50))
+    data = E.shard_engine_data(_data(g), mesh1)
+    pr = np.asarray(E.pagerank(data, iterations=50))
     want = nx.pagerank(nxg, alpha=0.85, max_iter=100, tol=1e-10)
     want_v = np.array([want[i] for i in range(g.num_vertices)])
     np.testing.assert_allclose(pr, want_v, rtol=5e-3, atol=1e-5)
@@ -40,8 +40,8 @@ def test_pagerank_matches_networkx(small, mesh1):
 
 def test_sssp_matches_networkx(small, mesh1):
     g, nxg = small
-    data = _data(g)
-    dist, iters = E.sssp(data, mesh1, source=0)
+    data = E.shard_engine_data(_data(g), mesh1)
+    dist, iters = E.sssp(data, source=0)
     lengths = nx.single_source_shortest_path_length(nxg, 0)
     got = np.asarray(dist)
     for v in range(g.num_vertices):
@@ -54,8 +54,8 @@ def test_sssp_matches_networkx(small, mesh1):
 
 def test_wcc_matches_networkx(small, mesh1):
     g, nxg = small
-    data = _data(g)
-    lab, _ = E.wcc(data, mesh1)
+    data = E.shard_engine_data(_data(g), mesh1)
+    lab, _ = E.wcc(data)
     lab = np.asarray(lab).astype(np.int64)
     comps = list(nx.connected_components(nxg))
     for comp in comps:
@@ -78,8 +78,30 @@ def test_geo_partition_has_fewer_mirrors_than_hash(small, mesh1):
 def test_pagerank_invariant_to_partitioning(small, mesh1):
     """Results must not depend on how edges are partitioned (engine soundness)."""
     g, _ = small
-    d1 = _data(g, 2)
-    d2 = E.build_engine_data(g, baselines.hash_1d(g, 7), 7)
-    p1 = np.asarray(E.pagerank(d1, mesh1, iterations=30))
-    p2 = np.asarray(E.pagerank(d2, mesh1, iterations=30))
+    d1 = E.shard_engine_data(_data(g, 2), mesh1)
+    d2 = E.shard_engine_data(E.build_engine_data(g, baselines.hash_1d(g, 7), 7), mesh1)
+    p1 = np.asarray(E.pagerank(d1, iterations=30))
+    p2 = np.asarray(E.pagerank(d2, iterations=30))
     np.testing.assert_allclose(p1, p2, rtol=1e-5, atol=1e-8)
+
+
+def test_sssp_new_root_compiles_nothing(small, mesh1):
+    """A second search on the same pack shape, from another root, reuses the
+    compiled program: the root is an operand, not a constant of the trace."""
+    from repro.obs import trace as OT
+
+    g, nxg = small
+    data = E.shard_engine_data(_data(g), mesh1)
+    tr = OT.Tracer()
+    # max_iters=23 is a program key no other test builds, so the first call
+    # must compile and the count below is known to see compiles.
+    with tr.span("first"):
+        E.sssp(data, source=0, max_iters=23)
+    with tr.span("second"):
+        dist, _ = E.sssp(data, source=5, max_iters=23)
+    counts = {s.name: s.counts or {} for s in tr.spans()}
+    assert counts["first"].get("compiles", 0) + counts["first"].get("cache_loads", 0) >= 1
+    assert counts["second"].get("compiles", 0) == 0
+    assert counts["second"].get("cache_loads", 0) == 0
+    lengths = nx.single_source_shortest_path_length(nxg, 5)
+    assert all(np.asarray(dist)[v] == d for v, d in lengths.items())

@@ -159,8 +159,9 @@ def test_sharded_rescale_acceptance_8dev():
         import numpy as np
         from repro.core import cep, ordering
         from repro.core.graph import rmat_graph
-        from repro.elastic.rescale_exec import EDGE_BYTES, ElasticRescaler
+        from repro.elastic.rescale_exec import ElasticRescaler
         from repro.graphs import engine as E
+        from repro.graphs.engine import EDGE_BYTES
         from repro.launch import mesh as MM
 
         g = rmat_graph(8, 6, seed=0)
@@ -177,16 +178,16 @@ def test_sharded_rescale_acceptance_8dev():
         # GAS runs directly over the sharded rows (k=12 ∤ 8 devices is fine);
         # must happen before the scale-in donates d12's buffers.
         pr = np.asarray(E.pagerank(d12, iterations=10))
-        ref = E.pack_ordered(src, dst, g.num_vertices, 12)
-        pr_ref = np.asarray(E.pagerank(ref, MM.make_test_mesh(1, 1), iterations=10))
+        ref = E.pack_ordered_sharded(src, dst, g.num_vertices, 12, MM.make_graph_mesh(1))
+        pr_ref = np.asarray(E.pagerank(ref, iterations=10))
         np.testing.assert_allclose(pr, pr_ref, rtol=1e-6, atol=1e-9)
         plan_in = cep.scale_plan(g.num_edges, 12, 8)
         back, s_in = r.execute(d12, plan_in, verify=True)
         assert s_in.cross_device_bytes == plan_in.migrated_bytes(EDGE_BYTES)
         orig = E.pack_ordered(src, dst, g.num_vertices, 8)
         got = E.unshard_engine_data(back)
-        np.testing.assert_array_equal(np.asarray(got.edges), np.asarray(orig.edges))
-        np.testing.assert_array_equal(np.asarray(got.mask), np.asarray(orig.mask))
+        np.testing.assert_array_equal(got.edges, orig.edges)
+        np.testing.assert_array_equal(got.mask, orig.mask)
         print("SHARDED-RESCALE-OK")
     """)
 

@@ -23,8 +23,8 @@ import numpy as np
 import pytest
 
 from repro.core import cep
-from repro.elastic.rescale_exec import EDGE_BYTES
 from repro.graphs import engine as E
+from repro.graphs.engine import EDGE_BYTES
 from repro.launch import multihost as MH
 from repro.launch import sharding as SH
 from repro.stream import IncrementalOrderer, SyntheticStream
@@ -114,8 +114,8 @@ def expected_global_pack(src, dst, num_vertices: int, k: int, g: int):
     edges = np.zeros((k_pad, e_max, 2), dtype=np.int32)
     mask = np.zeros((k_pad, e_max), dtype=np.float32)
     rows = [SH.partition_row(p, k, g) for p in range(k)]
-    edges[rows] = np.asarray(pack.edges)
-    mask[rows] = np.asarray(pack.mask)
+    edges[rows] = pack.edges
+    mask[rows] = pack.mask
     return edges, mask
 
 
@@ -314,7 +314,7 @@ def test_stream_acceptance_matches_host_replay_oracle(cluster):
     assert o.num_edges == records[0]["stream"]["num_edges"]
 
     pack = E.pack_slots(o.slot_src, o.slot_dst, o.slot_valid, o.regions, g.num_vertices)
-    want_edges, want_mask = np.asarray(pack.edges), np.asarray(pack.mask)
+    want_edges, want_mask = pack.edges, pack.mask
     k_pad = SH.padded_partition_count(o.regions, G_DEVICES)
     rows = [SH.partition_row(p, o.regions, G_DEVICES) for p in range(o.regions)]
     glob_edges = np.zeros((k_pad,) + want_edges.shape[1:], want_edges.dtype)
@@ -327,7 +327,7 @@ def test_stream_acceptance_matches_host_replay_oracle(cluster):
     got_deg = reassemble(shards, "stream_degrees", g.num_vertices)
     np.testing.assert_array_equal(got_edges, glob_edges)
     np.testing.assert_array_equal(got_mask, glob_mask)
-    np.testing.assert_array_equal(got_deg, np.asarray(pack.degrees))
+    np.testing.assert_array_equal(got_deg, pack.degrees)
 
 
 def test_stream_events_ordered_and_consistent_across_processes(cluster):
@@ -379,7 +379,7 @@ def test_async_rebuild_on_cluster_matches_host_replay_oracle(cluster):
         assert pc["full_reorder"]["misses"] == 1 and pc["splice"]["misses"] == 1
 
     pack = E.pack_slots(o.slot_src, o.slot_dst, o.slot_valid, o.regions, g.num_vertices)
-    want_edges, want_mask = np.asarray(pack.edges), np.asarray(pack.mask)
+    want_edges, want_mask = pack.edges, pack.mask
     k_pad = SH.padded_partition_count(o.regions, G_DEVICES)
     rows = [SH.partition_row(p, o.regions, G_DEVICES) for p in range(o.regions)]
     glob_edges = np.zeros((k_pad,) + want_edges.shape[1:], want_edges.dtype)

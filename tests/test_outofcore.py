@@ -298,8 +298,8 @@ def test_pack_slots_sharded_stream_matches_oracle():
         return slot_src[sl], slot_dst[sl], slot_valid[sl]
 
     sharded = GE.pack_slots_sharded_stream(part_fn, k, g.num_vertices, mesh, spr)
-    np.testing.assert_array_equal(np.asarray(sharded.edges), np.asarray(oracle.edges))
-    np.testing.assert_array_equal(np.asarray(sharded.mask), np.asarray(oracle.mask))
+    np.testing.assert_array_equal(np.asarray(sharded.edges), oracle.edges)
+    np.testing.assert_array_equal(np.asarray(sharded.mask), oracle.mask)
     np.testing.assert_array_equal(
         np.asarray(sharded.degrees), np.asarray(oracle.degrees)
     )
@@ -559,9 +559,9 @@ def test_commit_is_byte_identical_to_incore_oracle(cluster, oracle):
     got_edges = reassemble(shards, "commit_edges", OH.K_PACK)
     got_mask = reassemble(shards, "commit_mask", OH.K_PACK)
     got_deg = reassemble(shards, "commit_degrees", OH.PLAN.num_vertices)
-    np.testing.assert_array_equal(got_edges, np.asarray(pack.edges))
-    np.testing.assert_array_equal(got_mask, np.asarray(pack.mask))
-    np.testing.assert_array_equal(got_deg.reshape(-1), np.asarray(pack.degrees))
+    np.testing.assert_array_equal(got_edges, pack.edges)
+    np.testing.assert_array_equal(got_mask, pack.mask)
+    np.testing.assert_array_equal(got_deg.reshape(-1), pack.degrees)
 
 
 def test_rescale_roundtrip_returns_to_commit(cluster, oracle):
@@ -570,8 +570,8 @@ def test_rescale_roundtrip_returns_to_commit(cluster, oracle):
     own slot width, so raw shapes may differ by the scratch column)."""
     _, records, shards = cluster
     pack = oracle[3]
-    edges = np.asarray(pack.edges)
-    mask = np.asarray(pack.mask)
+    edges = pack.edges
+    mask = pack.mask
     back_edges = reassemble(shards, "rescale_back_edges", OH.K_PACK)
     back_mask = reassemble(shards, "rescale_back_mask", OH.K_PACK)
     for p in range(OH.K_PACK):

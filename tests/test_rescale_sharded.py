@@ -13,8 +13,9 @@ import pytest
 from repro.core import cep, ordering
 from repro.core.graph import rmat_graph
 from repro.elastic import controller as ec
-from repro.elastic.rescale_exec import EDGE_BYTES, ElasticRescaler
+from repro.elastic.rescale_exec import ElasticRescaler
 from repro.graphs import engine as E
+from repro.graphs.engine import EDGE_BYTES
 from repro.launch import mesh as MM
 from repro.launch import sharding as SH
 
@@ -74,16 +75,16 @@ def test_acceptance_8_12_8_bit_identical_and_thm2_cross_device(ordered, mesh, re
 
     want12 = E.pack_ordered(src, dst, g.num_vertices, 12)
     got12 = E.unshard_engine_data(d12)
-    np.testing.assert_array_equal(np.asarray(got12.edges), np.asarray(want12.edges))
-    np.testing.assert_array_equal(np.asarray(got12.mask), np.asarray(want12.mask))
+    np.testing.assert_array_equal(got12.edges, want12.edges)
+    np.testing.assert_array_equal(got12.mask, want12.mask)
 
     plan_in = cep.scale_plan(g.num_edges, 12, 8)
     back, s_in = rescaler.execute(d12, plan_in, verify=True)
     assert s_in.cross_device_bytes == plan_in.migrated_bytes(EDGE_BYTES)
     orig = E.pack_ordered(src, dst, g.num_vertices, 8)
     got8 = E.unshard_engine_data(back)
-    np.testing.assert_array_equal(np.asarray(got8.edges), np.asarray(orig.edges))
-    np.testing.assert_array_equal(np.asarray(got8.mask), np.asarray(orig.mask))
+    np.testing.assert_array_equal(got8.edges, orig.edges)
+    np.testing.assert_array_equal(got8.mask, orig.mask)
 
 
 @pytest.mark.parametrize("k_old,k_new", [(5, 9), (12, 20), (3, 7), (20, 16), (7, 8)])
@@ -110,29 +111,29 @@ def test_sharded_roundtrip_bit_identical_on_mesh(ordered, mesh, rescaler):
     back, _ = rescaler.rescale(d11, 5, verify=True)
     orig = E.pack_ordered(src, dst, g.num_vertices, 5)
     got = E.unshard_engine_data(back)
-    np.testing.assert_array_equal(np.asarray(got.edges), np.asarray(orig.edges))
-    np.testing.assert_array_equal(np.asarray(got.mask), np.asarray(orig.mask))
+    np.testing.assert_array_equal(got.edges, orig.edges)
+    np.testing.assert_array_equal(got.mask, orig.mask)
 
 
 def test_gas_apps_on_sharded_buffers_match_replicated(ordered, mesh):
     """PageRank / SSSP / WCC shard_map directly over the distributed rows and
-    must agree with the replicated single-buffer engine."""
+    must agree with the same programs on the host oracle committed to a
+    one-device graph mesh."""
     g, src, dst = ordered
-    ref = E.pack_ordered(src, dst, g.num_vertices, 12)
-    tm = MM.make_test_mesh(1, 1)
+    ref = E.pack_ordered_sharded(src, dst, g.num_vertices, 12, MM.make_graph_mesh(1))
     sdata = E.pack_ordered_sharded(src, dst, g.num_vertices, 12, mesh)
 
     np.testing.assert_allclose(
         np.asarray(E.pagerank(sdata, iterations=15)),
-        np.asarray(E.pagerank(ref, tm, iterations=15)),
+        np.asarray(E.pagerank(ref, iterations=15)),
         rtol=1e-6, atol=1e-9,
     )
     ds, its = E.sssp(sdata, source=0)
-    dr, itr = E.sssp(ref, tm, source=0)
+    dr, itr = E.sssp(ref, source=0)
     assert its == itr
     np.testing.assert_array_equal(np.asarray(ds), np.asarray(dr))
     ls, _ = E.wcc(sdata)
-    lr, _ = E.wcc(ref, tm)
+    lr, _ = E.wcc(ref)
     np.testing.assert_array_equal(np.asarray(ls), np.asarray(lr))
 
 
@@ -150,7 +151,7 @@ def test_controller_reports_executed_cross_device_traffic(ordered, mesh):
     g, src, dst = ordered
     t = [0.0]
     ctl = ec.ElasticController(8, dead_after_s=5.0, clock=lambda: t[0])
-    ctl.attach_engine(E.pack_ordered(src, dst, g.num_vertices, 8), mesh=mesh)
+    ctl.attach_engine(E.pack_ordered_sharded(src, dst, g.num_vertices, 8, mesh))
     t[0] = 1.0
     for h in range(7):
         ctl.heartbeat(h, 1)
@@ -166,7 +167,7 @@ def test_controller_reports_executed_cross_device_traffic(ordered, mesh):
     ).migrated_bytes(EDGE_BYTES)
     want = E.pack_ordered(src, dst, g.num_vertices, 7)
     got = E.unshard_engine_data(ctl.engine_data)
-    np.testing.assert_array_equal(np.asarray(got.edges), np.asarray(want.edges))
+    np.testing.assert_array_equal(got.edges, want.edges)
 
 
 def test_sharded_noop_and_degenerate_chunks_on_mesh(mesh):
@@ -182,4 +183,4 @@ def test_sharded_noop_and_degenerate_chunks_on_mesh(mesh):
     assert stats.oracle_checked
     want = E.pack_ordered(src, dst, g.num_vertices, k_new)
     got = E.unshard_engine_data(new)
-    np.testing.assert_array_equal(np.asarray(got.edges), np.asarray(want.edges))
+    np.testing.assert_array_equal(got.edges, want.edges)

@@ -11,7 +11,8 @@ from repro.data import pipeline as dp
 from repro.elastic import controller as ec
 from repro.elastic import expert_place as ep
 from repro.elastic import resharder as rs
-from repro.elastic.rescale_exec import ElasticRescaler, ProgramCache
+from repro.elastic.rescale_exec import ElasticRescaler
+from repro.program_cache import ProgramCache
 from repro.train import optimizer as O
 
 
@@ -214,15 +215,17 @@ def test_program_cache_resize_has_no_stale_reuse():
     src = np.arange(64, dtype=np.int64)
     dst = (src + 1) % 64
     from repro.graphs import engine as E
+    from repro.launch import mesh as MM
 
+    mesh = MM.make_graph_mesh(1)
     r1 = ElasticRescaler(program_cache_size=4)
     for k_new in (5, 6, 7):
-        r1.rescale(E.pack_ordered(src, dst, 64, 4), k_new)
+        r1.rescale(E.pack_ordered_sharded(src, dst, 64, 4, mesh), k_new)
     assert len(r1._programs) == 3 and r1.program_cache_size == 4
 
     r2 = ElasticRescaler(program_cache_size=1)
     assert len(r2._programs) == 0  # nothing carried over from r1
-    d2, _ = r2.rescale(E.pack_ordered(src, dst, 64, 4), 5)
+    d2, _ = r2.rescale(E.pack_ordered_sharded(src, dst, 64, 4, mesh), 5)
     r2.rescale(d2, 6)
     assert len(r2._programs) == 1  # new capacity enforced immediately
     assert list(r2._programs)[0][2:4] == (5, 6)  # only the latest program kept
@@ -286,10 +289,12 @@ def test_program_cache_hits_shared_across_rescale_kinds():
     src = np.arange(60, dtype=np.int64)
     dst = (src + 7) % 60
     from repro.graphs import engine as E
+    from repro.launch import mesh as MM
 
+    mesh = MM.make_graph_mesh(1)
     r = ElasticRescaler(program_cache_size=8)
     for _ in range(2):  # second lap = pure cache hits
-        d = E.pack_ordered(src, dst, 60, 4)
+        d = E.pack_ordered_sharded(src, dst, 60, 4, mesh)
         d, _ = r.rescale(d, 6)
         d, _ = r.rescale(d, 4)
     assert len(r._programs) == 2  # (4→6) and (6→4), each traced exactly once

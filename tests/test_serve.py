@@ -29,22 +29,27 @@ def _engine(scale=7, regions=2, seed=0, **kw):
 def test_query_programs_match_one_shot_entry_points():
     _, engine = _engine()
     data = engine.data
+    # The one-shot entry points on the host oracle of the same graph: the
+    # orderer's snapshot in the gap-free pack_ordered layout, committed to a
+    # one-device graph mesh — a check across layouts.
+    s, d = engine.orderer.snapshot()
+    ref = ge.pack_ordered_sharded(s, d, data.num_vertices, engine.k, MM.make_graph_mesh(1))
     ranks = ge.query_program(
         "pagerank", num_vertices=data.num_vertices, mesh=data.mesh, iterations=20
     )(data.edges, data.mask, data.degrees)
     np.testing.assert_allclose(
-        np.asarray(ranks), np.asarray(ge.pagerank(data)), rtol=1e-6, atol=1e-9
+        np.asarray(ranks), np.asarray(ge.pagerank(ref)), rtol=1e-6, atol=1e-9
     )
     dist, iters = ge.query_program(
         "sssp", num_vertices=data.num_vertices, mesh=data.mesh
     )(data.edges, data.mask, 3)
-    ref_dist, ref_iters = ge.sssp(data, source=3)
+    ref_dist, ref_iters = ge.sssp(ref, source=3)
     np.testing.assert_array_equal(np.asarray(dist), np.asarray(ref_dist))
     assert iters == ref_iters
     lab, _ = ge.query_program("wcc", num_vertices=data.num_vertices, mesh=data.mesh)(
         data.edges, data.mask
     )
-    np.testing.assert_array_equal(np.asarray(lab), np.asarray(ge.wcc(data)[0]))
+    np.testing.assert_array_equal(np.asarray(lab), np.asarray(ge.wcc(ref)[0]))
 
 
 def test_query_program_is_cached_and_source_is_an_operand():

@@ -479,10 +479,7 @@ def test_span_repair_oracle_mode_bit_identical_to_host_path():
             eng.verify_bit_identity()
         packs[mode] = E.unshard_engine_data(eng.data)
     for field in ("edges", "mask", "degrees"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(packs["oracle"], field)),
-            np.asarray(getattr(packs["host"], field)),
-        )
+        np.testing.assert_array_equal(getattr(packs["oracle"], field), getattr(packs["host"], field))
 
 
 def test_span_repair_device_mode_matches_mirror_over_stream():
@@ -820,16 +817,17 @@ def test_streaming_pack_runs_gas_between_ingests(ordered):
     eng = StreamingEngine(o, MM.make_graph_mesh(1))
     stream = SyntheticStream(g, batch_size=32, seed=6)
     eng.ingest(stream.batch(), verify=True)
-    # Reference: re-pack the orderer's snapshot from scratch.
+    # Reference: re-pack the orderer's snapshot from scratch, in the gap-free
+    # pack_ordered layout, committed to its own one-device mesh.
     s, d = o.snapshot()
-    ref = E.pack_ordered(s, d, g.num_vertices, 3)
+    ref = E.pack_ordered_sharded(s, d, g.num_vertices, 3, MM.make_graph_mesh(1))
     np.testing.assert_allclose(
         np.asarray(E.pagerank(eng.data, iterations=10)),
-        np.asarray(E.pagerank(ref, MM.make_test_mesh(1, 1), iterations=10)),
+        np.asarray(E.pagerank(ref, iterations=10)),
         rtol=1e-6, atol=1e-9,
     )
     ds, its = E.sssp(eng.data, source=0)
-    dr, itr = E.sssp(ref, MM.make_test_mesh(1, 1), source=0)
+    dr, itr = E.sssp(ref, source=0)
     assert its == itr
     np.testing.assert_array_equal(np.asarray(ds), np.asarray(dr))
 
@@ -839,10 +837,10 @@ def test_pack_slots_layout_and_scratch_column(ordered):
     o = IncrementalOrderer(src, dst, g.num_vertices, regions=4)
     data = E.pack_slots(o.slot_src, o.slot_dst, o.slot_valid, 4, g.num_vertices)
     assert data.edges.shape == (4, o.slots_per_region + 1, 2)
-    assert np.all(np.asarray(data.mask)[:, -1] == 0)  # scratch col always masked
+    assert np.all(data.mask[:, -1] == 0)  # scratch col always masked
     assert data.num_edges == o.num_edges and data.mirrors == -1
     # Occupied slots keep their (region, column) coordinates.
-    mask = np.asarray(data.mask)[:, :-1].reshape(-1)
+    mask = data.mask[:, :-1].reshape(-1)
     np.testing.assert_array_equal(mask.astype(bool), o.slot_valid)
 
 
@@ -851,10 +849,8 @@ def test_pack_ordered_slack_rows(ordered):
     tight = E.pack_ordered(src, dst, g.num_vertices, 4)
     slack = E.pack_ordered(src, dst, g.num_vertices, 4, e_max=int(tight.edges.shape[1]) + 7)
     assert slack.edges.shape[1] == tight.edges.shape[1] + 7
-    np.testing.assert_array_equal(
-        np.asarray(slack.edges)[:, : tight.edges.shape[1]], np.asarray(tight.edges)
-    )
-    assert np.all(np.asarray(slack.mask)[:, tight.edges.shape[1] :] == 0)
+    np.testing.assert_array_equal(slack.edges[:, : tight.edges.shape[1]], tight.edges)
+    assert np.all(slack.mask[:, tight.edges.shape[1] :] == 0)
     with pytest.raises(ValueError, match="e_max"):
         E.pack_ordered(src, dst, g.num_vertices, 4, e_max=1)
 
@@ -1063,7 +1059,7 @@ def test_attached_stream_takes_precedence_over_engine_data(ordered):
     o = IncrementalOrderer(src, dst, g.num_vertices, regions=5)
     eng = StreamingEngine(o, MM.make_graph_mesh(1))
     ctl = ec.ElasticController(4)
-    ctl.attach_engine(E.pack_ordered(src, dst, g.num_vertices, 4))
+    ctl.attach_engine(E.pack_ordered_sharded(src, dst, g.num_vertices, 4, MM.make_graph_mesh(1)))
     ctl.attach_stream(eng)
     ev = ctl.add_hosts(1)  # k_new = 5 == stream.k: nothing to execute
     assert ev.k_new == 5 and not ev.executed and ctl.rescale_stats == []

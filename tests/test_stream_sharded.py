@@ -83,10 +83,10 @@ def test_sharded_rescale_under_ingest_with_cross_device_accounting(ordered, mesh
     eng.ingest(stream.batch(), verify=True)
     # GAS still runs on the migrated streaming pack.
     s, d = o.snapshot()
-    ref = E.pack_ordered(s, d, g.num_vertices, 5)
+    ref = E.pack_ordered_sharded(s, d, g.num_vertices, 5, MM.make_graph_mesh(1))
     np.testing.assert_allclose(
         np.asarray(E.pagerank(eng.data, iterations=10)),
-        np.asarray(E.pagerank(ref, MM.make_test_mesh(1, 1), iterations=10)),
+        np.asarray(E.pagerank(ref, iterations=10)),
         rtol=1e-6, atol=1e-9,
     )
 
